@@ -27,13 +27,12 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import ml_dtypes
 
 BLOCK = 16                      # NVFP4 block size
 E2M1_MAX = 6.0                  # max magnitude representable in E2M1
 E4M3_MAX = 448.0                # max magnitude representable in E4M3 (fn)
 FP8_E4M3 = jnp.float8_e4m3fn
-FP4_E2M1 = ml_dtypes.float4_e2m1fn   # not re-exported by jnp on all versions
+FP4_E2M1 = jnp.float4_e2m1fn
 
 # Weight-memory footprint of one NVFP4 element, in bytes:
 #   4 bits code + 8 bits E4M3 scale / 16 elems  (+ amortized fp32 tensor scale)

@@ -27,6 +27,10 @@ K so a GEMM consumes whole blocks):
 K; ``x`` is padded with zeros to match — the pad region of the codes is
 zero, so it contributes nothing.
 
+Byte j holds K element 2j in its low nibble and 2j+1 in its high one.  The
+wrapper splits x into its even and odd columns, so the kernel contracts
+each with one nibble plane as stored and never interleaves them.
+
 Grid (n, m, k) with K innermost; an FP32 VMEM scratch tile accumulates
 across K steps and is flushed to the output on the last step.
 """
@@ -50,40 +54,66 @@ def _nibble_to_f32(n):
     return sign * mag
 
 
-def _dequant_tile(codes, scales, s_tensor):
-    """codes [tn, tk/2] + scales [tn, >=tk/16] -> BF16-rounded w [tn, tk] f32.
+def _lane_map(a, width: int, hit):
+    """``out[:, j] = a[:, i]`` where ``hit(i, j)`` — at most one i per
+    column j, zero where none — as a [rows, width] matmul with a 0/1 matrix.
 
-    ``scales`` may be WIDER than tk/16 — the lane-aligned "lane128" layout
-    pads each K-tile's scale strip to 128 lanes so the scale operand tiles
-    cleanly on the TPU lane dim when lowering through Mosaic; the dequant
-    only consumes the leading tk/16 columns either way.
+    Mosaic lowers no reshape that splits or merges the lane dim, so the
+    block-scale repeat runs on the MXU.  Exact for bf16-representable ``a``
+    (E4M3 scales): each output is one product with 1.0 plus zeros,
+    accumulated in fp32.
     """
-    tn, tk2 = codes.shape
-    lo = _nibble_to_f32(codes & jnp.uint8(0xF))
-    hi = _nibble_to_f32(codes >> 4)
-    w = jnp.stack([lo, hi], axis=-1).reshape(tn, tk2 * 2)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (a.shape[1], width), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (a.shape[1], width), 1)
+    m = hit(rows, cols).astype(jnp.bfloat16)
+    return jax.lax.dot_general(a.astype(jnp.bfloat16), m,
+                               (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
+
+def _dequant_planes(codes, scales, s_tensor):
+    """codes [tn, tk/2] + scales [tn, >=tk/16] -> the BF16 weights of K's
+    even and odd elements, two [tn, tk/2] planes.
+
+    Byte j of a row holds element 2j in its low nibble and 2j+1 in its
+    high one, so the planes are the nibbles as stored: the caller splits x
+    into its even and odd columns instead of interleaving the weights.
+    Only the per-block scale broadcast (one scale per 8 bytes) needs a lane
+    map.  ``scales`` may be WIDER than tk/16 — the "lane128" layout pads
+    each K-tile's strip to 128 lanes; only the leading tk/16 are read.
+    """
+    half = codes.shape[1]
+    c = codes.astype(jnp.int32)           # Mosaic casts no uint8 to float
+    s = _lane_map(scales.astype(jnp.float32), half,
+                  lambda i, j: j // (BLOCK // 2) == i) * s_tensor
     # apply two-level scales, then round to BF16 — the MXU operand precision,
     # and exactly the values the QDQ serving path stores
-    s = scales[:, : tk2 * 2 // BLOCK].astype(jnp.float32) * s_tensor
-    w = (w.reshape(tn, tk2 * 2 // BLOCK, BLOCK) * s[..., None]
-         ).reshape(tn, tk2 * 2)
-    return w.astype(jnp.bfloat16).astype(jnp.float32)
+    return ((_nibble_to_f32(c & 0xF) * s).astype(jnp.bfloat16),
+            (_nibble_to_f32(c >> 4) * s).astype(jnp.bfloat16))
 
 
-def _matmul_kernel(s_tensor_ref, x_ref, codes_ref, scales_ref, o_ref, acc_ref,
-                   *, n_k_steps: int):
+def _tile_dot(x_even, x_odd, codes, scales, s_tensor):
+    """One K tile's fp32 contribution to x @ W^T: x_even/x_odd [tm, tk/2]
+    are x's even and odd columns, codes/scales the weight tile."""
+    lo, hi = _dequant_planes(codes, scales, s_tensor)
+    f32 = jnp.float32
+    dims = (((1,), (1,)), ((), ()))
+    return (jax.lax.dot_general(x_even.astype(f32), lo.astype(f32), dims,
+                                preferred_element_type=f32)
+            + jax.lax.dot_general(x_odd.astype(f32), hi.astype(f32), dims,
+                                  preferred_element_type=f32))
+
+
+def _matmul_kernel(s_tensor_ref, xe_ref, xo_ref, codes_ref, scales_ref,
+                   o_ref, acc_ref, *, n_k_steps: int):
     k_step = pl.program_id(2)
 
     @pl.when(k_step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _dequant_tile(codes_ref[...], scales_ref[...], s_tensor_ref[0, 0])
-    x = x_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += _tile_dot(xe_ref[...], xo_ref[...], codes_ref[...],
+                              scales_ref[...], s_tensor_ref[0, 0])
 
     @pl.when(k_step == n_k_steps - 1)
     def _flush():
@@ -97,7 +127,7 @@ def swizzle_scales(scales: jax.Array, tile_k: int) -> jax.Array:
     tile_k=512) on the TPU lane dimension — a sub-lane-width operand Mosaic
     would have to mask-pad on every tile fetch.  The "lane128" layout gives
     each K-tile a full 128-lane strip: tile ki's scales live at lanes
-    [ki*128, ki*128 + tile_k/16), zero-padded to 128.  ``_dequant_tile``
+    [ki*128, ki*128 + tile_k/16), zero-padded to 128.  ``_dequant_planes``
     reads only the leading tile_k/16 lanes of its strip, so the kernel body
     is layout-agnostic and the swizzle is a pure host-side relayout (done
     once at weight-load time on TPU; the interpret path keeps compact).
@@ -187,7 +217,8 @@ def nvfp4_matmul(x: jax.Array, packed: PackedNVFP4, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1), lambda ni, mi, ki: (0, 0)),
-            pl.BlockSpec((tm, tk), lambda ni, mi, ki: (mi, ki)),
+            pl.BlockSpec((tm, tk // 2), lambda ni, mi, ki: (mi, ki)),
+            pl.BlockSpec((tm, tk // 2), lambda ni, mi, ki: (mi, ki)),
             pl.BlockSpec((tn, tk // 2), lambda ni, mi, ki: (ni, ki)),
             pl.BlockSpec((tn, sk), lambda ni, mi, ki: (ni, ki)),
         ],
@@ -196,7 +227,7 @@ def nvfp4_matmul(x: jax.Array, packed: PackedNVFP4, *,
         # fp32 accumulator tile lives in VMEM across the K loop
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
         interpret=interpret,
-    )(s_tensor, xm, codes, scales)
+    )(s_tensor, xm[:, 0::2], xm[:, 1::2], codes, scales)
 
     if pm or pn:
         out = out[:m, :n]
@@ -208,19 +239,16 @@ def nvfp4_matmul(x: jax.Array, packed: PackedNVFP4, *,
 # ---------------------------------------------------------------------------
 
 
-def _grouped_kernel(s_tensor_ref, x_ref, codes_ref, scales_ref, o_ref,
-                    acc_ref, *, n_k_steps: int):
+def _grouped_kernel(s_tensor_ref, xe_ref, xo_ref, codes_ref, scales_ref,
+                    o_ref, acc_ref, *, n_k_steps: int):
     k_step = pl.program_id(3)
 
     @pl.when(k_step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = _dequant_tile(codes_ref[0], scales_ref[0], s_tensor_ref[0, 0, 0])
-    x = x_ref[0].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += _tile_dot(xe_ref[0], xo_ref[0], codes_ref[0],
+                              scales_ref[0], s_tensor_ref[0, 0, 0])
 
     @pl.when(k_step == n_k_steps - 1)
     def _flush():
@@ -293,7 +321,10 @@ def nvfp4_matmul_grouped(x: jax.Array, packed: PackedNVFP4, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, 1), lambda gi, ni, mi, ki: (gi, 0, 0)),
-            pl.BlockSpec((1, tm, tk), lambda gi, ni, mi, ki: (gi, mi, ki)),
+            pl.BlockSpec((1, tm, tk // 2),
+                         lambda gi, ni, mi, ki: (gi, mi, ki)),
+            pl.BlockSpec((1, tm, tk // 2),
+                         lambda gi, ni, mi, ki: (gi, mi, ki)),
             pl.BlockSpec((1, tn, tk // 2),
                          lambda gi, ni, mi, ki: (gi, ni, ki)),
             pl.BlockSpec((1, tn, sk), lambda gi, ni, mi, ki: (gi, ni, ki)),
@@ -303,7 +334,7 @@ def nvfp4_matmul_grouped(x: jax.Array, packed: PackedNVFP4, *,
         out_shape=jax.ShapeDtypeStruct((g, mm, nn), out_dtype),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
         interpret=interpret,
-    )(s_tensor, xm, codes, scales)
+    )(s_tensor, xm[..., 0::2], xm[..., 1::2], codes, scales)
 
     if pm or pn:
         out = out[:, :m, :n]
@@ -340,7 +371,6 @@ def nvfp4_matmul_tp(x: jax.Array, packed: PackedNVFP4, mesh,
     ``in_specs`` are resharded by GSPMD — correctness never depends on the
     caller's placement, only zero-comm efficiency does.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     *lead, k = x.shape
@@ -357,9 +387,9 @@ def nvfp4_matmul_tp(x: jax.Array, packed: PackedNVFP4, mesh,
             return nvfp4_matmul(xl, p, out_dtype=out_dtype,
                                 interpret=interpret, **tile_kw)
 
-        y = shard_map(local, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)(xm, packed.codes, packed.scales,
-                                       s_tensor)
+        y = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False)(
+            xm, packed.codes, packed.scales, s_tensor)
     elif parallelism == "row":
         n_shards = int(dict(mesh.shape)[axis])
         local_k = packed.k // n_shards
@@ -374,9 +404,9 @@ def nvfp4_matmul_tp(x: jax.Array, packed: PackedNVFP4, mesh,
                                 interpret=interpret, **tile_kw)
             return jax.lax.psum(part, axis)
 
-        y = shard_map(local, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)(xm, packed.codes, packed.scales,
-                                       s_tensor)
+        y = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False)(
+            xm, packed.codes, packed.scales, s_tensor)
         y = y.astype(out_dtype)
     else:
         raise ValueError(f"unknown parallelism {parallelism!r}")

@@ -27,15 +27,25 @@ path is this kernel's oracle, and the engine's greedy tokens must not move
 when fusion is switched on.  A running-rescale online softmax reassociates
 the exp/sum arithmetic, which perturbs BF16 probabilities by 1 ulp often
 enough to flip greedy argmaxes over a long decode.  Instead the kernel
-streams pages in one pass, buffering the f32 score strip [R, MB*bs] and the
-dequantized V pages in VMEM scratch, and runs the softmax ONCE over the
-fully-masked strip on the last grid step — the associativity-sensitive math
-happens exactly once, in the oracle's order, so BF16-KV greedy decode is
-bitwise-stable under fusion.  VMEM cost is s_alloc*(4*R + 2*hd) bytes per
-(batch, kv-head) program — ~9 MB at 32k context, hd 128, R 8 — the right
-trade for decode, where R = n_rep * q_len is tiny.  (A rescaling online
-softmax only wins when the score strip itself is too big, i.e. large R —
-the prefill regime, which ``blockwise_attention`` already covers.)
+streams pages in one pass, buffering the dequantized K and V pages in VMEM
+scratch, and scores, masks and softmaxes the whole strip ONCE on the last
+grid step — the associativity-sensitive math happens exactly once, in the
+oracle's order, so BF16-KV greedy decode is bitwise-stable under fusion.
+On the TPU that holds for k+1 verify; for one-token decode XLA lowers the
+oracle's one-query dots as multiply+reduce instead of on the MXU, and the
+two agree only within the bf16 roundings of p and of the output
+(``chip_smoke.py`` checks both).  (A rescaling online softmax only wins
+when the strip is too big for VMEM — the prefill regime, which
+``blockwise_attention`` already covers.)
+
+Mosaic layout: a block's two minor dims must be (8, 128)-aligned or whole,
+so a grid step reads a page's [bs, g*hd] slab for the g KV heads that fill
+128 lanes (the pool viewed as [n_blocks, bs, Hkv*hd]) and buffers it whole
+in a [s_alloc, g*hd] scratch strip, so no lane is padding at hd 64.  The
+strips and the score and probability rows must fit Mosaic's scoped VMEM
+(``vmem_bytes``): at qwen1.5-0.5b's heads that holds to 24k context, and
+32k does not compile, so ``serve.Engine`` falls back to the two-step where
+``fits_vmem`` says no.
 """
 from __future__ import annotations
 
@@ -47,46 +57,89 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# Mosaic's default scoped VMEM limit on TPU v5e
+SCOPED_VMEM_BYTES = 16 * 2**20
 
 
-def _attend_kernel(bt_ref, q_ref, pos_ref, k_ref, v_ref, ks_ref, vs_ref,
-                   o_ref, s_scr, v_scr, *, mb: int, bs: int, n_rep: int,
-                   s_q: int, window: int, fp8: bool):
-    """grid (B, Hkv, MB); page j arrives via the scalar-prefetched table."""
-    j = pl.program_id(2)
-    r = n_rep * s_q
+def _heads_per_step(hkv: int, hd: int) -> int:
+    """KV heads one grid step reads: the fewest whose [bs, g*hd] page slab
+    is 128-lane aligned (Mosaic tiles a block's minor dim by 128 lanes, or
+    takes it whole), else all of them."""
+    for g in range(1, hkv + 1):
+        if hkv % g == 0 and (g * hd) % 128 == 0:
+            return g
+    return hkv
 
-    k = k_ref[0, :, 0, :]                                # [bs, hd]
-    v = v_ref[0, :, 0, :]
-    if fp8:
-        k = (k.astype(jnp.float32) * ks_ref[0, :, 0][:, None])
-        v = (v.astype(jnp.float32) * vs_ref[0, :, 0][:, None])
-    k = k.astype(q_ref.dtype)
-    v = v.astype(q_ref.dtype)
 
-    q = q_ref[0, 0]                                      # [R, hd]
-    hd = q.shape[-1]
-    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    s_scr[:, pl.ds(j * bs, bs)] = s
-    v_scr[pl.ds(j * bs, bs), :] = v
+def vmem_bytes(s_alloc: int, hkv: int, hd: int, rows: int,
+               itemsize: int = 2) -> int:
+    """VMEM one grid step holds: the K and V scratch strips [s_alloc, g*hd]
+    plus, for each of its g heads, an fp32 score and probability strip
+    [rows, s_alloc] (``rows`` = q heads per KV head x query length, padded
+    to 8 sublanes).  Calibrated against the TPU v5e compiler: every shape
+    it refused for lack of VMEM estimates above ``SCOPED_VMEM_BYTES``."""
+    g = _heads_per_step(hkv, hd)
+    lanes = -(-g * hd // 128) * 128
+    sublanes = -(-rows // 8) * 8
+    return 2 * s_alloc * lanes * itemsize + 2 * g * sublanes * s_alloc * 4
+
+
+def fits_vmem(s_alloc: int, hkv: int, hd: int, rows: int) -> bool:
+    return vmem_bytes(s_alloc, hkv, hd, rows) <= SCOPED_VMEM_BYTES
+
+
+def _attend_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
+                   o_ref, k_scr, v_scr, *, mb: int, bs: int, s_q: int,
+                   g: int, window: int, fp8: bool):
+    """grid (B, Hkv/g, MB); page j arrives via the scalar-prefetched table.
+
+    Each step buffers its page's K and V for the step's g heads; the last
+    step attends over the whole buffered strip in the oracle's op order.
+    """
+    bi, hg, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    r, hd = q_ref.shape[-2:]
+    page = pl.ds(pl.multiple_of(j * bs, bs), bs)
+    if not fp8:
+        k_scr[page, :] = k_ref[0].astype(k_scr.dtype)    # [bs, g*hd]
+        v_scr[page, :] = v_ref[0].astype(v_scr.dtype)
+    for c in range(g if fp8 else 0):
+        lanes = slice(c * hd, (c + 1) * hd)
+        # this head's scale column: a one-hot select (exact), since the
+        # head index is dynamic and the scale block spans all heads
+        head = jax.lax.broadcasted_iota(jnp.int32, ks_ref.shape[1:], 1)
+        pick = (head == hg * g + c).astype(jnp.float32)
+        ks = jnp.sum(ks_ref[0] * pick, axis=1, keepdims=True)
+        vs = jnp.sum(vs_ref[0] * pick, axis=1, keepdims=True)
+        k = k_ref[0, :, lanes].astype(jnp.float32) * ks  # [bs, hd]
+        v = v_ref[0, :, lanes].astype(jnp.float32) * vs
+        k_scr[page, lanes] = k.astype(k_scr.dtype)
+        v_scr[page, lanes] = v.astype(v_scr.dtype)
 
     @pl.when(j == mb - 1)
     def _attend():
         # per-query valid-key counts -> the oracle's position mask; the
         # q rows are laid out [n_rep, s_q] so row i's query index is i % s_q
-        qpos = jnp.broadcast_to(pos_ref[0][None, :], (n_rep, s_q)).reshape(r)
+        row = jax.lax.broadcasted_iota(jnp.int32, (r, 1), 0) % s_q
+        qpos = jnp.zeros((r, 1), jnp.int32)
+        for t in range(s_q):
+            qpos = jnp.where(row == t, pos_ref[bi, t], qpos)
         slot = jax.lax.broadcasted_iota(jnp.int32, (r, mb * bs), 1)
-        valid = slot < qpos[:, None]
+        valid = slot < qpos
         if window:
-            valid &= slot >= qpos[:, None] - window
-        sm = jnp.where(valid, s_scr[...], NEG_INF)
-        p = jax.nn.softmax(sm, axis=-1)
-        out = jax.lax.dot_general(p.astype(q_ref.dtype), v_scr[...],
-                                  (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+            valid &= slot >= qpos - window
+        scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+        for c in range(g):
+            lanes = slice(c * hd, (c + 1) * hd)
+            q = q_ref[0, c]                              # [R, hd]
+            sc = jax.lax.dot_general(q, k_scr[:, lanes],
+                                     (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            sm = jnp.where(valid, sc * scale, NEG_INF)
+            p = jax.nn.softmax(sm, axis=-1)
+            out = jax.lax.dot_general(p.astype(q.dtype), v_scr[:, lanes],
+                                      (((1,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+            o_ref[0, c] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -108,6 +161,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     n_rep = h // hkv
     r = n_rep * s_q
     fp8 = k_scale is not None
+    g = _heads_per_step(hkv, hd)
 
     # head h = hkv_idx * n_rep + rep (repeat_kv layout) -> group by kv head
     q4 = q.reshape(b, s_q, hkv, n_rep, hd).transpose(0, 2, 3, 1, 4)
@@ -115,45 +169,48 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     pos = jnp.asarray(pos, jnp.int32)
     pos2 = jnp.broadcast_to(pos[:, None] if pos.ndim == 1 else pos, (b, s_q))
     bt = jnp.asarray(block_tables, jnp.int32)
+    # a page's heads side by side on the lanes (a row-major reshape)
+    k_flat = k_pages.reshape(n_blocks, bs, hkv * hd)
+    v_flat = v_pages.reshape(n_blocks, bs, hkv * hd)
 
-    def k_map(bi, hi, ji, bt):
-        return (bt[bi, ji], 0, hi, 0)
-
-    def ks_map(bi, hi, ji, bt):
+    def page_map(bi, hi, ji, bt, pos):
         return (bt[bi, ji], 0, hi)
 
+    def scale_map(bi, hi, ji, bt, pos):
+        return (bt[bi, ji], 0, 0)
+
     in_specs = [
-        pl.BlockSpec((1, 1, r, hd), lambda bi, hi, ji, bt: (bi, hi, 0, 0)),
-        pl.BlockSpec((1, s_q), lambda bi, hi, ji, bt: (bi, 0)),
-        pl.BlockSpec((1, bs, 1, hd), k_map),
-        pl.BlockSpec((1, bs, 1, hd), k_map),
+        pl.BlockSpec((1, g, r, hd),
+                     lambda bi, hi, ji, bt, pos: (bi, hi, 0, 0)),
+        pl.BlockSpec((1, bs, g * hd), page_map),
+        pl.BlockSpec((1, bs, g * hd), page_map),
     ]
-    args = [q4, pos2, k_pages, v_pages]
+    args = [q4, k_flat, v_flat]
     if fp8:
-        in_specs += [pl.BlockSpec((1, bs, 1), ks_map),
-                     pl.BlockSpec((1, bs, 1), ks_map)]
+        in_specs += [pl.BlockSpec((1, bs, hkv), scale_map)] * 2
         args += [k_scale, v_scale]
     else:
         # dummy scalars (kernel ignores them when fp8=False)
-        in_specs += [pl.BlockSpec((1, 1), lambda bi, hi, ji, bt: (0, 0))] * 2
+        in_specs += [pl.BlockSpec((1, 1),
+                                  lambda bi, hi, ji, bt, pos: (0, 0))] * 2
         args += [jnp.zeros((1, 1), jnp.float32)] * 2
 
-    kern = functools.partial(_attend_kernel, mb=mb, bs=bs, n_rep=n_rep,
-                             s_q=s_q, window=window, fp8=fp8)
+    kern = functools.partial(_attend_kernel, mb=mb, bs=bs, s_q=s_q, g=g,
+                             window=window, fp8=fp8)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, hkv, mb),
+            num_scalar_prefetch=2,
+            grid=(b, hkv // g, mb),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, r, hd),
-                                   lambda bi, hi, ji, bt: (bi, hi, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((r, mb * bs), jnp.float32),
-                            pltpu.VMEM((mb * bs, hd), q.dtype)],
+            out_specs=pl.BlockSpec((1, g, r, hd),
+                                   lambda bi, hi, ji, bt, pos: (bi, hi, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((mb * bs, g * hd), q.dtype),
+                            pltpu.VMEM((mb * bs, g * hd), q.dtype)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, r, hd), q.dtype),
         interpret=interpret,
-    )(bt, *args)
+    )(bt, pos2, *args)
 
     out = out.reshape(b, hkv, n_rep, s_q, hd).transpose(0, 3, 1, 2, 4)
     return out.reshape(b, s_q, h, hd)

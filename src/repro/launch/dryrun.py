@@ -30,9 +30,12 @@ from repro.core.qconfig import BF16
 from repro.distributed import ctx as shd_ctx
 from repro.distributed import sharding as shd
 from repro.launch import hlo_analysis, roofline, specs
-from repro.launch.mesh import make_production_mesh, set_mesh_ctx
+from repro.launch.mesh import make_production_mesh
 from repro.models import get_model
 from repro.optim import AdamW
+
+# The chip the production mesh stands for: a 16x16 pod of TPU v5e.
+TARGET_KIND = "TPU v5 lite"
 
 
 def build_step(cfg, shape, qadcfg=None, weight_format="qdq"):
@@ -106,7 +109,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     copts = {"xla_dump_to": dump_dir,
              "xla_dump_hlo_pass_re": "spmd-partitioning"}
     t0 = time.time()
-    with set_mesh_ctx(mesh), shd_ctx.use(mesh, rules):
+    with jax.set_mesh(mesh), shd_ctx.use(mesh, rules):
         if kind == "train":
             state, batch = specs.train_inputs(cfg, shape, mesh, rules,
                                               AdamW(state_dtype="float32"))
@@ -127,15 +130,13 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):          # older jax returns [dict]
-        ca = ca[0] if ca else {}
     # analyze the post-SPMD, pre-backend HLO (per-device shapes, original
     # scan trip counts — see hlo_analysis docstring)
     spmd_files = sorted(glob.glob(
         os.path.join(dump_dir, "*after_spmd-partitioning*.txt")))
     hlo = open(spmd_files[-1]).read() if spmd_files else compiled.as_text()
     stats = hlo_analysis.analyze_hlo(hlo, n_chips)
-    rf = roofline.compute(cfg, shape, stats, n_chips)
+    rf = roofline.compute(cfg, shape, stats, n_chips, TARGET_KIND)
     shutil.rmtree(dump_dir, ignore_errors=True)
 
     cell.update({
@@ -150,7 +151,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             "peak_bytes_per_device": int(ma.argument_size_in_bytes
                                          + ma.temp_size_in_bytes),
             "fits_hbm": bool(ma.argument_size_in_bytes + ma.temp_size_in_bytes
-                             < roofline.HW["hbm_cap"]),
+                             < roofline.hw(TARGET_KIND)["hbm_cap"]),
         },
         "cost_analysis": {"flops": float(ca.get("flops", 0.0)),
                           "bytes_accessed": float(ca.get("bytes accessed", 0.0))},

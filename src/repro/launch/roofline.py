@@ -1,8 +1,11 @@
-"""Roofline terms for TPU v5e from a compiled dry-run cell.
+"""Roofline terms for a TPU chip from a compiled dry-run cell.
 
-    compute_s    = FLOPs_per_chip / 197e12         (bf16 MXU peak)
-    memory_s     = HBM_bytes_per_chip / 819e9
-    collective_s = collective_bytes_per_chip / 50e9 (per-link ICI)
+    compute_s    = FLOPs_per_chip / peak_flops     (bf16 MXU peak)
+    memory_s     = HBM_bytes_per_chip / hbm_bw
+    collective_s = collective_bytes_per_chip / ici_bw (per link)
+
+with the chip's published peaks from ``HW``, keyed by jax's
+``device_kind``.
 
 FLOPs/bytes come from the HLO parser (``hlo_analysis`` — scan-aware), with
 ``compiled.cost_analysis()`` reported alongside as a cross-check.
@@ -15,12 +18,27 @@ import dataclasses
 
 from repro.configs import ModelConfig, ShapeConfig
 
+# Published per-chip peaks keyed by ``jax.Device.device_kind``.  TPU v5e
+# ("TPU v5 lite"): Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 16 GiB HBM at 819 GB/s, 1,600 Gbit/s ICI over 4 links (50 GB/s per link).
 HW = {
-    "peak_flops": 197e12,        # bf16 / chip
-    "hbm_bw": 819e9,             # bytes/s
-    "ici_bw": 50e9,              # bytes/s/link
-    "hbm_cap": 16 * 2**30,       # bytes
+    "TPU v5 lite": {
+        "peak_flops": 197e12,        # bf16 / chip
+        "hbm_bw": 819e9,             # bytes/s
+        "ici_bw": 50e9,              # bytes/s/link
+        "hbm_cap": 16 * 2**30,       # bytes
+    },
 }
+
+
+def hw(device_kind: str) -> dict:
+    """Published peaks of ``device_kind``; a kind not in ``HW`` is an error
+    (a roofline against some other chip's peaks would be silently wrong)."""
+    try:
+        return HW[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device_kind "
+                         f"{device_kind!r}; known: {sorted(HW)}") from None
 
 
 @dataclasses.dataclass
@@ -88,15 +106,16 @@ def _attn_decode_flops(cfg, cache_len, batch) -> float:
 
 
 def compute(cfg: ModelConfig, shape: ShapeConfig, hlo_stats: dict,
-            n_chips: int) -> Roofline:
+            n_chips: int, device_kind: str) -> Roofline:
+    peaks = hw(device_kind)
     mf_chip = model_flops(cfg, shape) / n_chips
     hf = hlo_stats["flops_per_device"]
     by = hlo_stats["bytes_per_device"]
     cb = hlo_stats["collective_bytes_per_device"]
 
-    c_s = hf / HW["peak_flops"]
-    m_s = by / HW["hbm_bw"]
-    k_s = cb / HW["ici_bw"]
+    c_s = hf / peaks["peak_flops"]
+    m_s = by / peaks["hbm_bw"]
+    k_s = cb / peaks["ici_bw"]
     terms = {"compute": c_s, "memory": m_s, "collective": k_s}
     dominant = max(terms, key=terms.get)
     step = max(c_s, m_s, k_s)
@@ -107,5 +126,5 @@ def compute(cfg: ModelConfig, shape: ShapeConfig, hlo_stats: dict,
         dominant=dominant,
         useful_ratio=mf_chip / hf if hf else 0.0,
         step_s=step,
-        mfu=(mf_chip / HW["peak_flops"]) / step if step else 0.0,
+        mfu=(mf_chip / peaks["peak_flops"]) / step if step else 0.0,
     )

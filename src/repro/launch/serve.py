@@ -105,19 +105,22 @@ def inject_quant_noise(params, scale: float):
 
 
 def serve_batch(cfg, params, prompts, n_gen: int, sample_rng=None, qcfg=None,
-                extras=None):
+                extras=None, forced=None, s_max: int | None = None):
     """Prefill + greedy decode ``n_gen`` tokens for a [B, P] prompt batch.
 
     ``qcfg`` overrides the recipe-derived serving config; serving always
     disables runtime weight fake-quant (weights are pre-quantized offline —
     re-QDQ'ing already-gridded weights would derive fresh, different scales).
     ``extras`` adds batched non-token prefill inputs (e.g. ``enc_frames``
-    [B, T, d] for encoder-decoder archs).
+    [B, T, d] for encoder-decoder archs).  ``forced`` [B, >= n_gen]
+    teacher-forces the loop: each step feeds the forced token instead of
+    the argmax, and the stats carry ``logits`` [B, n_gen, V] fp32, row i
+    scoring token i.  ``s_max`` sizes the KV cache (default P + n_gen).
     """
     model = get_model(cfg)
     sq = (dataclasses.replace(qcfg, quantize_weights=False)
           if qcfg is not None else specs.serve_qconfig(cfg))
-    s_max = prompts.shape[1] + n_gen
+    s_max = s_max or prompts.shape[1] + n_gen
 
     prefill = jax.jit(lambda p, b: model.prefill(cfg, p, b, sq, s_max=s_max))
     step = jax.jit(lambda p, c, b: model.decode_step(cfg, p, c, b, sq),
@@ -131,11 +134,18 @@ def serve_batch(cfg, params, prompts, n_gen: int, sample_rng=None, qcfg=None,
     jax.block_until_ready(logits)
     t_prefill = time.time() - t0
 
-    out = [jnp.argmax(logits[:, -1:], -1).astype(jnp.int32)]
+    def pick(i, logits):
+        if forced is None:
+            return jnp.argmax(logits[:, -1:], -1).astype(jnp.int32)
+        rows.append(logits[:, -1])
+        return jnp.asarray(forced, jnp.int32)[:, i:i + 1]
+
+    rows = []
+    out = [pick(0, logits)]
     t0 = time.time()
-    for _ in range(n_gen - 1):
+    for i in range(1, n_gen):
         logits, cache = step(params, cache, {"tokens": out[-1]})
-        out.append(jnp.argmax(logits[:, -1:], -1).astype(jnp.int32))
+        out.append(pick(i, logits))
     jax.block_until_ready(out[-1])
     t_decode = time.time() - t0
     tokens = jnp.concatenate(out, axis=1)
@@ -144,11 +154,34 @@ def serve_batch(cfg, params, prompts, n_gen: int, sample_rng=None, qcfg=None,
     # decode loop alone, e2e_tok_s rates all returned tokens over prefill +
     # decode wall time.
     b = prompts.shape[0]
-    return tokens, {"prefill_s": t_prefill, "decode_s": t_decode,
-                    "decode_steps": n_gen - 1, "n_tokens": b * n_gen,
-                    "decode_tok_s": b * (n_gen - 1) / max(t_decode, 1e-9),
-                    "e2e_tok_s": b * n_gen
-                    / max(t_prefill + t_decode, 1e-9)}
+    stats = {"prefill_s": t_prefill, "decode_s": t_decode,
+             "decode_steps": n_gen - 1, "n_tokens": b * n_gen,
+             "decode_tok_s": b * (n_gen - 1) / max(t_decode, 1e-9),
+             "e2e_tok_s": b * n_gen / max(t_prefill + t_decode, 1e-9)}
+    if forced is not None:
+        stats["logits"] = np.asarray(jnp.stack(rows, 1), np.float32)
+    return tokens, stats
+
+
+def teacher_forced_gap(got, want) -> dict:
+    """Compare two sides' logits [T, V] for the same prompt and the same
+    fed tokens (one side teacher-forced along the other's stream).
+
+    ``rel`` is max |got - want| over the std of ``want`` (``rows`` per
+    position, ``rows[0]`` the prefill row).  ``splits`` lists the
+    positions where ``got``'s greedy choice is not ``want``'s, each with
+    ``want``'s margin between its own choice and ``got``'s, in the same
+    std units: a split is a near-tie only if that margin is small.
+    """
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    std = float(want.std())
+    rows = np.abs(got - want).max(-1) / std
+    pick_got, pick_want = got.argmax(-1), want.argmax(-1)
+    at = np.arange(len(want))
+    margin = (want[at, pick_want] - want[at, pick_got]) / std
+    return {"rel": float(rows.max()), "rows": rows.tolist(),
+            "splits": [(int(i), float(margin[i]))
+                       for i in np.flatnonzero(pick_got != pick_want)]}
 
 
 def weight_report(params) -> dict:
@@ -286,19 +319,27 @@ def _ms(v) -> str:
     return f"{v * 1e3:.1f}ms" if v is not None else "n/a"
 
 
-def _run_workload(eng, prompts, extras_list, gen: int):
+def run_workload(eng, prompts, extras_list, gen: int, forced_list=None,
+                 keep_logits: bool = False):
     """Submit the staggered mixed workload and drain it.
 
     Half the requests go in up front, the rest trickle in one engine step
     apart — deterministic, so two engines fed the same prompt list see the
     SAME arrival pattern (the basis of the cache-on/off A/B check).
+    ``forced_list`` (one stream per prompt) and ``keep_logits`` go to
+    ``Engine.submit``.
     """
+    forced_list = forced_list or [None] * len(prompts)
+
+    def submit(i):
+        return eng.submit(np.asarray(prompts[i]), gen, extras=extras_list[i],
+                          forced=forced_list[i], keep_logits=keep_logits)
+
     half = len(prompts) // 2
-    rids = [eng.submit(np.asarray(p), gen, extras=ex)
-            for p, ex in zip(prompts[:half], extras_list[:half])]
-    for p, ex in zip(prompts[half:], extras_list[half:]):
+    rids = [submit(i) for i in range(half)]
+    for i in range(half, len(prompts)):
         eng.step()
-        rids.append(eng.submit(np.asarray(p), gen, extras=ex))
+        rids.append(submit(i))
     outputs = eng.drain(max_steps=10_000)
     return rids, outputs
 
@@ -340,7 +381,7 @@ def run_engine(cfg, params, qcfg, args, mesh=None, rules=None) -> dict:
             for i in range(len(prompts))]
     # staggered arrivals: half up front, the rest trickle in while the
     # first wave is already decoding
-    rids, outputs = _run_workload(eng, prompts, extras_list, args.gen)
+    rids, outputs = run_workload(eng, prompts, extras_list, args.gen)
     st = eng.stats()
 
     ok = len(outputs) == args.requests
@@ -399,8 +440,8 @@ def run_engine(cfg, params, qcfg, args, mesh=None, rules=None) -> dict:
         base_args.metrics_out = base_args.trace_out = None
         base_args.shadow_rate = 0.0
         base_eng, _ = build_engine(cfg, params, qcfg, base_args, mesh, rules)
-        base_rids, base_out = _run_workload(base_eng, prompts, extras_list,
-                                            args.gen)
+        base_rids, base_out = run_workload(base_eng, prompts, extras_list,
+                                           args.gen)
         cache_parity = len(base_out) == len(outputs)
         for rid, brid in zip(rids, base_rids):
             if not np.array_equal(outputs.get(rid, np.empty(0, np.int32)),
@@ -709,4 +750,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

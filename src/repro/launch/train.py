@@ -106,6 +106,7 @@ def train(arch: str, smoke: bool = True, steps: int = 200, lr: float = 1e-3,
                  for k in ev[0]}
             m["step"] = i + 1
             m["loss"] = float(metrics["loss"])
+            m["grad_norm"] = float(metrics["grad_norm"])
             history.append(m)
             log(f"[train] step {i+1} " +
                 " ".join(f"{k}={v:.4f}" for k, v in m.items() if k != "step"))
@@ -158,4 +159,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -372,7 +372,8 @@ def paged_attend_fused(q, pool_sl, block_tables, pos, *, window: int = 0):
     Same contract as ``paged_attend`` (its parity oracle: bitwise for BF16
     pools — the kernel defers softmax until the fully-masked score strip is
     resident, so no rescaling reassociation — and per-element-identical FP8
-    dequant).  Single-device only: a ``pallas_call`` cannot be partitioned
+    dequant; on the TPU one-token decode agrees within bf16 rounding, see
+    ``kernels.paged_attention``).  Single-device only: a ``pallas_call`` cannot be partitioned
     by GSPMD, so mesh-traced paths keep the gather+attend two-step
     (``serve.engine`` resolves ``fused_kernels="auto"`` accordingly).
     """

@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.core.qconfig import BF16
 from repro.distributed import ctx as shd_ctx
+from repro.kernels import paged_attention
 from repro.models import common, decoder
 from repro.models.registry import get_model
 from repro.obs import NOOP as OBS_NOOP
@@ -71,6 +72,9 @@ class Engine:
     static-shape functions — TP only changes where the bytes live.
     """
 
+    # one decode step emits one token per request from one logits row
+    scores_per_token = True
+
     def __init__(self, cfg, params, qcfg=None, *, n_slots: int = 8,
                  block_size: int = 16, n_blocks: int = 48,
                  max_blocks_per_slot: int = 8,
@@ -78,7 +82,7 @@ class Engine:
                  prefill_budget: int | None = None, eos_id: int | None = None,
                  mesh=None, rules=None, fused_kernels: str = "auto",
                  prefix_cache: bool = False, kv_alloc: str = "reserve",
-                 headroom: int = 2,
+                 headroom: int = 2, max_q_len: int = 1,
                  obs=None, shadow_teacher=None, shadow_rate: float = 0.0):
         # refuse unservable configs before touching params or quant policy
         plan = state_mod.check_supported(cfg)
@@ -123,8 +127,11 @@ class Engine:
         # --- fused serving-kernel tier -------------------------------------
         # "on"/"off" force it; "auto" enables it when the fused kernels can
         # serve this config: paged-KV state plan (the fused attention kernel
-        # streams pool pages) and no mesh (pallas_call does not partition
-        # under GSPMD — TP keeps the shard_map'd 2-D GEMM + gather attend).
+        # streams pool pages), no mesh (pallas_call does not partition
+        # under GSPMD — TP keeps the shard_map'd 2-D GEMM + gather attend)
+        # and a page strip that fits the kernel's VMEM.  ``max_q_len`` is
+        # the most queries one attention call scores (1 for decode; the
+        # speculative engine passes k+1; paged prefill feeds whole blocks).
         if fused_kernels not in ("on", "off", "auto"):
             raise ValueError(f"fused_kernels={fused_kernels!r}: "
                              "expected 'on', 'off' or 'auto'")
@@ -135,9 +142,23 @@ class Engine:
         if fused_kernels == "on" and mesh is not None:
             raise ValueError("fused_kernels='on' is single-device only; "
                              "drop the mesh or use 'auto'")
+        fits = True
+        if self.paged:
+            q_len = max(max_q_len,
+                        block_size if prefill_mode == "paged" else 1)
+            strip = (max_blocks_per_slot * block_size, cfg.n_kv_heads,
+                     cfg.head_dim, cfg.n_heads // cfg.n_kv_heads * q_len)
+            fits = paged_attention.fits_vmem(*strip)
+            if fused_kernels == "on" and not fits:
+                need = paged_attention.vmem_bytes(*strip) / 2**20
+                limit = paged_attention.SCOPED_VMEM_BYTES / 2**20
+                raise ValueError(
+                    f"fused_kernels='on': paged attention over {strip[0]} "
+                    f"keys needs {need:.1f} MiB of VMEM, more than "
+                    f"{limit:.0f} MiB; use 'auto' for the two-step")
         self.fused = (fused_kernels == "on"
                       or (fused_kernels == "auto" and self.paged
-                          and mesh is None))
+                          and mesh is None and fits))
         if self.fused and self.sq.packed_backend == "auto":
             # route 3-D packed MoE expert stacks through the grouped Pallas
             # GEMM instead of dequant-to-HBM + einsum
@@ -318,15 +339,31 @@ class Engine:
 
     def submit(self, prompt, max_new_tokens: int,
                sampling: SamplingParams | None = None,
-               extras: dict | None = None) -> int:
+               extras: dict | None = None, forced=None,
+               keep_logits: bool = False) -> int:
         """Queue a request; returns its id.  Admission happens in step().
 
         ``extras`` carries non-token prefill inputs (unbatched; the engine
         adds the batch dim) — e.g. ``{"enc_frames": [T, n_mels]}`` for
-        encoder-decoder archs.
+        encoder-decoder archs.  ``forced`` (at least ``max_new_tokens``
+        ids) teacher-forces the request: it emits these tokens instead of
+        sampling, so its logits score a given continuation.
+        ``keep_logits`` keeps, in ``logits(rid)``, the logits row each
+        emitted token was chosen from.
         """
+        if (forced is not None or keep_logits) and not self.scores_per_token:
+            raise NotImplementedError(
+                f"{type(self).__name__} scores draft chunks, not one token "
+                "per step: forced / keep_logits need the plain Engine")
+        if forced is not None and len(forced) < max_new_tokens:
+            raise ValueError(f"forced holds {len(forced)} tokens, fewer "
+                             f"than max_new_tokens={max_new_tokens}")
         req = self.sched.submit(prompt, max_new_tokens, sampling,
                                 step=self.step_count, extras=extras)
+        if forced is not None:
+            req.forced = np.asarray(forced, np.int32)
+        if keep_logits:
+            req.logits = []
         req.submit_t = time.monotonic()
         req.submit_wall_t = time.time()     # the one wall-clock anchor
         self._m_req_submitted.inc()
@@ -377,6 +414,12 @@ class Engine:
             self.step()
             steps += 1
         return self.outputs()
+
+    def logits(self, rid: int) -> np.ndarray:
+        """[n_emitted, V] fp32 logits of a finished request submitted
+        with ``keep_logits``: row i is what its token i was chosen from."""
+        rows = self.sched.finished[rid].logits
+        return np.asarray(jnp.stack(rows), np.float32)
 
     def outputs(self) -> dict[int, np.ndarray]:
         return {rid: np.asarray(r.output, np.int32)
@@ -699,7 +742,8 @@ class Engine:
         for r in reqs:
             r.n_cached += 1
             r.n_written = max(r.n_written, r.n_cached)
-            self._emit(r, int(sampled[r.slot]), finished)
+            self._emit(r, self._chosen(r, int(sampled[r.slot]),
+                                       logits[r.slot, 0]), finished)
 
     # -- shared ------------------------------------------------------------
 
@@ -839,7 +883,17 @@ class Engine:
             jnp.asarray([req.sampling.top_k], jnp.int32),
             jnp.asarray([req.sampling.seed], jnp.int32),
             jnp.asarray([len(req.output)], jnp.int32))
-        return int(tok[0])
+        return self._chosen(req, int(tok[0]), logits[0])
+
+    @staticmethod
+    def _chosen(req: Request, sampled: int, row: jax.Array) -> int:
+        """The token ``req`` emits next — its forced token, if it has one
+        — keeping the logits ``row`` it came from when asked."""
+        if req.logits is not None:
+            req.logits.append(row)
+        if req.forced is not None:
+            return int(req.forced[len(req.output)])
+        return sampled
 
     def _emit(self, req: Request, tok: int, finished: list[Request]) -> None:
         req.output.append(tok)

@@ -55,6 +55,10 @@ class Request:
     extras: Optional[dict] = None         # non-token prefill inputs, e.g.
     #                                       {"enc_frames": [T, n_mels]} for
     #                                       encoder-decoder archs
+    forced: Optional[np.ndarray] = None   # tokens emitted in place of
+    #                                       sampled ones (teacher forcing)
+    logits: Optional[list] = None         # when a list: the logits row
+    #                                       [V] each emitted token came from
 
     state: str = WAITING
     slot: Optional[int] = None

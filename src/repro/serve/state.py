@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.distributed.sharding import device_bytes
 from repro.models import common, decoder
 from repro.models.registry import get_model, serve_capabilities
 
@@ -131,16 +132,6 @@ def slab_bytes_per_slot(specs, n_slots: int) -> int:
 
 def _tree_nbytes(tree) -> int:
     return sum(int(a.nbytes) for a in jax.tree.leaves(tree))
-
-
-def _tree_nbytes_per_device(tree) -> int:
-    def one(a):
-        try:
-            db = a.sharding.shard_shape(a.shape)
-            return int(np.prod(db)) * a.dtype.itemsize
-        except Exception:
-            return int(a.nbytes)
-    return sum(one(a) for a in jax.tree.leaves(tree))
 
 
 # ---------------------------------------------------------------------------
@@ -529,7 +520,7 @@ class SlabState:
             "peak_utilization": self.peak_used / max(self.n_slots, 1),
             "fp8": False,
             "pool_bytes": _tree_nbytes(self.data),
-            "pool_bytes_per_device": _tree_nbytes_per_device(self.data),
+            "pool_bytes_per_device": device_bytes(self.data),
             "state_bytes_per_slot": slab_bytes_per_slot(self.specs,
                                                         self.n_slots),
             "state_dense_bound": self.dense_bound,

@@ -68,10 +68,12 @@ class SpecEngine(Engine):
     the draft only moves the acceptance rate.
     """
 
+    scores_per_token = False
+
     def __init__(self, cfg, params, qcfg=None, *, draft_k: int = 4,
                  draft: str = "self-qdq", draft_layers: int = 0,
                  draft_model=None, adaptive_k: bool = False, **kw):
-        super().__init__(cfg, params, qcfg, **kw)
+        super().__init__(cfg, params, qcfg, max_q_len=draft_k + 1, **kw)
         if draft_k < 1:
             raise ValueError(f"draft_k must be >= 1, got {draft_k}")
         self.spec_k = int(draft_k)

@@ -386,3 +386,42 @@ def test_chunked_prefill_mixed_workload_completes(loaded):
     assert sorted(outputs) == sorted(rids)
     assert all(len(outputs[r]) == 4 for r in rids)
     assert eng.pool.used_blocks == 0
+
+
+@pytest.mark.parametrize("acts", ["nvfp4", "bf16"])
+def test_engine_logits_match_teacher_forced_serve_batch(loaded, acts):
+    """The engine's own logits (``keep_logits``) against ``serve_batch``
+    teacher-forced along the engine's stream, as ``chip_smoke.py`` gates
+    them: bitwise here, where both run the same arithmetic.  A lost KV
+    page, an engine defect that batching invariance cannot see since
+    every run shares it, moves them far past the smoke's tolerance."""
+    cfg, by_fmt = loaded
+    params, qcfg = by_fmt["packed"]
+    qcfg = dataclasses.replace(qcfg,
+                               quantize_activations=(acts == "nvfp4"))
+    prompts = _prompts(cfg, [9, 14])
+
+    def run(lose_page):
+        eng = _engine(cfg, params, qcfg)
+        rids = [eng.submit(p, GEN, keep_logits=True) for p in prompts]
+        eng.step()                                  # prefill + first decode
+        if lose_page:
+            blk = eng.sched.running()[0].block_ids[0]
+            eng.pool.data = jax.tree.map(lambda a: a.at[:, blk].set(0),
+                                         eng.pool.data)
+        out = eng.drain(max_steps=500)
+        return [(out[r], eng.logits(r)) for r in rids]
+
+    for lose_page in (False, True):
+        gaps = []
+        for prompt, (stream, got) in zip(prompts, run(lose_page)):
+            assert got.shape == (GEN, cfg.vocab_size)
+            _, st = serve.serve_batch(cfg, params, jnp.asarray(prompt[None]),
+                                      GEN, qcfg=qcfg, forced=stream[None],
+                                      s_max=32)
+            gaps.append(serve.teacher_forced_gap(got, st["logits"][0]))
+        if not lose_page:
+            assert all(g["rel"] == 0.0 and not g["splits"] for g in gaps)
+        else:
+            assert gaps[0]["rows"][0] == 0.0      # prefill ran before
+            assert max(g["rel"] for g in gaps) > 1.0, gaps

@@ -308,3 +308,33 @@ def test_engine_fused_kernels_validation():
     params, qcfg = serve.load_quantized(cfg, jax.random.PRNGKey(0), "qdq")
     with pytest.raises(ValueError, match="fused_kernels"):
         Engine(cfg, params, qcfg, fused_kernels="maybe")
+
+
+@pytest.mark.parametrize("blocks,fused", [(4, True), (2048, False)],
+                         ids=["fits", "over_vmem"])
+def test_engine_auto_falls_back_when_strip_exceeds_vmem(blocks, fused):
+    """A page strip past the kernel's scoped VMEM (32k keys at
+    qwen1.5-0.5b's heads) serves through the two-step under "auto", and
+    "on" refuses it instead of failing to compile."""
+    from repro import configs
+    from repro.kernels import paged_attention
+    from repro.launch import serve
+    from repro.serve import Engine
+
+    full = configs.get_config("qwen1.5-0.5b")
+    strip = (full.n_kv_heads, full.head_dim, 1)
+    assert paged_attention.fits_vmem(1056, *strip)
+    assert not paged_attention.fits_vmem(32768, *strip)
+
+    cfg = configs.get_smoke("qwen1.5-0.5b")
+    params, qcfg = serve.load_quantized(cfg, jax.random.PRNGKey(0), "qdq")
+    kw = dict(n_slots=1, block_size=16, n_blocks=8,
+              max_blocks_per_slot=blocks)
+    assert paged_attention.fits_vmem(
+        blocks * 16, cfg.n_kv_heads, cfg.head_dim,
+        cfg.n_heads // cfg.n_kv_heads) == fused
+    assert Engine(cfg, params, qcfg, fused_kernels="auto", **kw).fused \
+        == fused
+    if not fused:
+        with pytest.raises(ValueError, match="VMEM"):
+            Engine(cfg, params, qcfg, fused_kernels="on", **kw)

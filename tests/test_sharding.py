@@ -148,6 +148,16 @@ def test_hlo_collective_factors():
 # ---------------------------------------------- 8-device GSPMD integration
 
 
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from the published table for the chip jax reports; an
+    unknown kind is an error, never another chip's numbers."""
+    from repro.launch import roofline
+    assert roofline.hw("TPU v5 lite")["peak_flops"] == 197e12
+    assert roofline.hw("TPU v5 lite")["hbm_bw"] == 819e9
+    with pytest.raises(ValueError, match="cpu"):
+        roofline.hw("cpu")
+
+
 @pytest.mark.slow
 def test_small_mesh_train_step_runs():
     """Real (host-emulated 8-device) pjit execution of a QAD train step —
@@ -163,6 +173,7 @@ def test_small_mesh_train_step_runs():
         from repro.data import DataConfig, make_batch
         from repro.distributed import sharding as shd, ctx
         from repro.launch import specs
+        from repro.launch.mesh import make_host_mesh
         from repro.models import get_model, common
         from repro.optim import AdamW
 
@@ -177,11 +188,9 @@ def test_small_mesh_train_step_runs():
         step = qad.make_train_step(model, cfg, qcfg, opt)
         _, m_single = jax.jit(step)(state, batch)   # 1-logical-device baseline
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_host_mesh(model_parallel=2)            # (4, 2)
         rules = shd.make_rules(mesh, "fsdp_tp")
         shard_p = shd.tree_shardings(model.param_specs(cfg), mesh, rules)
-        # (jax.sharding.AxisType / jax.set_mesh are newer-jax APIs; on 0.4.x
-        # NamedSharding-annotated inputs + the repo's cst() context suffice)
         with ctx.use(mesh, rules):
             state_sh = qad.TrainState(
                 step=state.step,

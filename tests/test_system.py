@@ -1,4 +1,6 @@
 """End-to-end system behaviour: train driver, serve driver, generated data."""
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -72,3 +74,27 @@ def test_packed_weight_serving_matches_qdq():
     np.testing.assert_allclose(np.asarray(up),
                                np.asarray(w_q, np.float32), rtol=1e-2,
                                atol=1e-3)
+
+
+@pytest.mark.parametrize("placed", [True, False], ids=["env", "checkout"])
+def test_compile_cache_placement(monkeypatch, tmp_path, placed):
+    """The entry points' compile cache: where JAX_COMPILATION_CACHE_DIR is
+    set it is left to jax and nothing else is set; otherwise the cache goes
+    to the fixed directory inside the checkout."""
+    from repro.launch import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    if placed:
+        monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+    else:
+        monkeypatch.delenv(compile_cache.ENV, raising=False)
+    try:
+        got = compile_cache.enable_compile_cache()
+        if placed:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            checkout = Path(__file__).resolve().parents[1]
+            assert got == str(checkout / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -14,14 +14,15 @@ from repro.models.common import ParamSpec
 
 TP_PRELUDE = textwrap.dedent("""
     import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
     import sys; sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
 """)
 
 
-def _run(script: str, timeout: int = 900):
-    r = subprocess.run([sys.executable, "-c", TP_PRELUDE + script],
+def _run(script: str, timeout: int = 900, devices: int = 2):
+    r = subprocess.run([sys.executable, "-c",
+                        TP_PRELUDE.format(n=devices) + script],
                        capture_output=True, text=True, cwd=".",
                        timeout=timeout)
     return r
@@ -127,7 +128,8 @@ def test_packed_gemm_shard_map_parity():
     (full K per shard); row-parallel is psum'd fp32 partials (tolerance)."""
     r = _run(textwrap.dedent("""
         from repro.kernels import ops
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(model_parallel=2)            # (1, 2)
         rng = jax.random.PRNGKey(0)
         x = jax.random.normal(rng, (5, 64), jnp.bfloat16)
         w = jax.random.normal(jax.random.fold_in(rng, 1), (64, 96),
@@ -189,6 +191,74 @@ def test_engine_tp_token_parity_dense_packed():
         print("TP_ENGINE_OK")
     """))
     assert "TP_ENGINE_OK" in r.stdout, r.stdout + r.stderr
+
+
+def test_tp_logit_gap_is_rounding_and_a_misaligned_shard_is_not():
+    """What ``chip_smoke.py --chips 4`` rests on, at 2 layers and 4 host
+    devices: the TP=4 engine's own logits, teacher-forced along the
+    one-device engine's stream, against the one-device engine's, in units
+    of the logits' std.  TP and a harmless reordering (the dequant-einsum
+    backend instead of the Pallas kernel) stay far inside the smoke's
+    tolerance of 0.5; a row-parallel shard reading its neighbour's block
+    scales lands far outside it, with BF16 or NVFP4 activations."""
+    r = _run(textwrap.dedent("""
+        import dataclasses
+        from repro import configs
+        from repro.distributed import sharding as shd
+        from repro.kernels import ops
+        from repro.launch import serve
+        from repro.launch.mesh import make_host_mesh
+        from repro.serve import Engine
+
+        cfg = dataclasses.replace(configs.get_smoke("qwen1.5-0.5b"),
+                                  d_model=256, d_ff=768, vocab_size=2048)
+        params, qcfg = serve.load_quantized(cfg, jax.random.PRNGKey(0),
+                                            "packed")
+        mesh = make_host_mesh(model_parallel=4)
+        rules = shd.make_rules(mesh, "tp_only")
+        prompt = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (24,),
+                                               4, cfg.vocab_size))
+        kernel_tp = ops._nvfp4_matmul_tp
+
+        def misaligned_tp(x, packed, mesh, parallelism, **kw):
+            if parallelism == "row":      # each shard's scales off by a block
+                s = packed.scales
+                lead, n = s.shape[:-1], dict(mesh.shape)["model"]
+                s = jnp.roll(s.reshape(*lead, n, -1), 1, -1).reshape(s.shape)
+                packed = dataclasses.replace(packed, scales=s)
+            return kernel_tp(x, packed, mesh, parallelism, **kw)
+
+        def logits(q, forced=None, tp=False, backend=None, fault=False):
+            if backend:
+                q = dataclasses.replace(q, packed_backend=backend)
+            ops._nvfp4_matmul_tp = misaligned_tp if fault else kernel_tp
+            eng = Engine(cfg, params, q, n_slots=2, block_size=8,
+                         n_blocks=16, max_blocks_per_slot=6,
+                         mesh=mesh if tp else None, rules=rules if tp else None,
+                         fused_kernels="off")
+            rid = eng.submit(prompt, 8, forced=forced, keep_logits=True)
+            out = eng.drain(max_steps=500)
+            ops._nvfp4_matmul_tp = kernel_tp
+            return out[rid], eng.logits(rid)
+
+        gap = {}
+        for acts in ("bf16", "nvfp4"):
+            q = dataclasses.replace(qcfg,
+                                    quantize_activations=(acts == "nvfp4"))
+            stream, one = logits(q)
+            for name, kw in [("tp", dict(tp=True)),
+                             ("reordered", dict(backend="dequant")),
+                             ("misaligned", dict(tp=True, fault=True))]:
+                got = logits(q, stream, **kw)[1]
+                gap[acts, name] = serve.teacher_forced_gap(got, one)["rel"]
+        print(gap)
+        for acts in ("bf16", "nvfp4"):
+            assert gap[acts, "tp"] < 0.05, gap
+            assert gap[acts, "misaligned"] > 1.0, gap
+        assert gap["bf16", "reordered"] < 0.1, gap
+        print("TP_SETTLED_OK")
+    """), devices=4)
+    assert "TP_SETTLED_OK" in r.stdout, r.stdout + r.stderr
 
 
 @pytest.mark.slow
