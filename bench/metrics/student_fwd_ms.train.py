@@ -1,0 +1,8 @@
+"""Device time per QAD step of the NVFP4 student's forward, QDQ, GEMMs
+and lm_head (``qad.student``), in the traced window: the own time of the
+ops whose HLO instruction carries that phase (``qad_phases``)."""
+import qad_phases
+
+
+def read(ctx):
+    return qad_phases.read(ctx, "student_fwd")

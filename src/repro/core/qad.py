@@ -43,6 +43,15 @@ class QADConfig:
     temperature: float = 1.0    # paper uses T=1 for exact distribution match
 
 
+# The step's phases as ``jax.named_scope``s: every HLO instruction of the
+# compiled step carries its scope in ``metadata.op_name`` (the student's
+# backward as ``transpose(jvp(qad.student))``), so a device profile's op
+# events join to a phase by instruction name.  Scopes are metadata only:
+# the step computes bitwise what it computes without them.
+PHASES = ("qad.teacher", "qad.student", "qad.kl", "qad.optimizer")
+TEACHER, STUDENT, KL, OPTIMIZER = PHASES
+
+
 def init_state(model, cfg, rng, opt, with_teacher: bool = True) -> TrainState:
     params = model.init_params(cfg, rng)
     teacher = jax.tree.map(jnp.copy, params) if with_teacher else None
@@ -58,16 +67,19 @@ def make_loss_fn(model, cfg, qcfg: QuantConfig, qad: QADConfig):
         t = qad.temperature
 
         if qad.use_chunked_loss and qad.loss == "kl":
-            h_s = model.apply(cfg, student, batch, qcfg, output="hidden")
-            h_t = model.apply(cfg, teacher, batch, BF16, output="hidden")
-            h_t = jax.lax.stop_gradient(h_t)
-            w_s = model.unembed(cfg, student)
-            w_t = jax.lax.stop_gradient(model.unembed(cfg, teacher))
-            # keep lm_head quantization parity with the plain path
-            h_s = qcfg.q_act(h_s, "lm_head")
-            w_s = qcfg.q_weight(w_s, "lm_head", contract_axis=0)
-            kl = losses.chunked_kl_loss(h_t, w_t, h_s, w_s, mask,
-                                        qad.loss_chunks)
+            with jax.named_scope(STUDENT):
+                h_s = model.apply(cfg, student, batch, qcfg, output="hidden")
+                w_s = model.unembed(cfg, student)
+                # keep lm_head quantization parity with the plain path
+                h_s = qcfg.q_act(h_s, "lm_head")
+                w_s = qcfg.q_weight(w_s, "lm_head", contract_axis=0)
+            with jax.named_scope(TEACHER):
+                h_t = model.apply(cfg, teacher, batch, BF16, output="hidden")
+                h_t = jax.lax.stop_gradient(h_t)
+                w_t = jax.lax.stop_gradient(model.unembed(cfg, teacher))
+            with jax.named_scope(KL):
+                kl = losses.chunked_kl_loss(h_t, w_t, h_s, w_s, mask,
+                                            qad.loss_chunks)
             return kl, {"kl": kl}
 
         # numerics probes (repro.obs.numerics): with qcfg.numerics on, a
@@ -77,14 +89,16 @@ def make_loss_fn(model, cfg, qcfg: QuantConfig, qad: QADConfig):
         # The context managers run at trace time; numerics=False (the
         # default) takes the exact pre-probe path.
         tape = obs_numerics.Tape() if qcfg.numerics else None
-        if tape is not None:
-            with obs_numerics.collecting(tape):
+        with jax.named_scope(STUDENT):
+            if tape is not None:
+                with obs_numerics.collecting(tape):
+                    s_logits = model.apply(cfg, student, batch, qcfg)
+                s_aux = tape.drain()
+            else:
                 s_logits = model.apply(cfg, student, batch, qcfg)
-            s_aux = tape.drain()
-        else:
-            s_logits = model.apply(cfg, student, batch, qcfg)
         metrics = {}
-        ce = losses.ce_from_logits(s_logits, batch["labels"], mask)
+        with jax.named_scope(KL):
+            ce = losses.ce_from_logits(s_logits, batch["labels"], mask)
         metrics["ce"] = ce
 
         if qad.loss == "ce":                       # QAT
@@ -92,25 +106,29 @@ def make_loss_fn(model, cfg, qcfg: QuantConfig, qad: QADConfig):
                 metrics["numerics"] = _numerics_metrics(s_aux, None, mask)
             return ce, metrics
 
-        if tape is not None:
-            t_qcfg = dataclasses.replace(BF16, numerics=True)
-            with obs_numerics.collecting(tape):
+        with jax.named_scope(TEACHER):
+            if tape is not None:
+                t_qcfg = dataclasses.replace(BF16, numerics=True)
+                with obs_numerics.collecting(tape):
+                    t_logits = jax.lax.stop_gradient(
+                        model.apply(cfg, teacher, batch, t_qcfg))
+                t_aux = tape.drain()
+            else:
                 t_logits = jax.lax.stop_gradient(
-                    model.apply(cfg, teacher, batch, t_qcfg))
-            t_aux = tape.drain()
-        else:
-            t_logits = jax.lax.stop_gradient(
-                model.apply(cfg, teacher, batch, BF16))
-        kl = losses.kl_from_logits(t_logits / t, s_logits / t, mask)
+                    model.apply(cfg, teacher, batch, BF16))
+        with jax.named_scope(KL):
+            kl = losses.kl_from_logits(t_logits / t, s_logits / t, mask)
+            metrics["top1_agree"] = losses.top1_agreement(t_logits, s_logits,
+                                                          mask)
         metrics["kl"] = kl
-        metrics["top1_agree"] = losses.top1_agreement(t_logits, s_logits, mask)
         if tape is not None:
             metrics["numerics"] = _numerics_metrics(s_aux, t_aux, mask)
 
         if qad.loss == "kl":                       # QAD
             return kl, metrics
         if qad.loss == "mse":                      # Table 8 ablation
-            mse = losses.mse_from_logits(t_logits, s_logits, mask)
+            with jax.named_scope(KL):
+                mse = losses.mse_from_logits(t_logits, s_logits, mask)
             metrics["mse"] = mse
             return mse, metrics
         if qad.loss == "kl+ce":
@@ -151,13 +169,14 @@ def make_train_step(model, cfg, qcfg: QuantConfig, opt,
     def step(state: TrainState, batch) -> tuple[TrainState, dict]:
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.student, state.teacher, batch)
-        updates, opt_state = opt.update(grads, state.opt_state, state.student,
-                                        state.step)
-        student = jax.tree.map(lambda p, u: (p + u).astype(p.dtype),
-                               state.student, updates)
-        metrics = dict(metrics, loss=loss,
-                       grad_norm=_global_norm(grads),
-                       update_norm=_global_norm(updates))
+        with jax.named_scope(OPTIMIZER):
+            updates, opt_state = opt.update(grads, state.opt_state,
+                                            state.student, state.step)
+            student = jax.tree.map(lambda p, u: (p + u).astype(p.dtype),
+                                   state.student, updates)
+            metrics = dict(metrics, loss=loss,
+                           grad_norm=_global_norm(grads),
+                           update_norm=_global_norm(updates))
         if qcfg.numerics and isinstance(grads, dict) and "layers" in grads:
             # per-layer grad norm: every stacked-layer leaf carries the
             # [n_layers, ...] leading dim, so reduce all trailing axes

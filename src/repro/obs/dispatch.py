@@ -19,12 +19,20 @@ it for the dynamic extent of a block (the engine wraps ``step()``), and
 This module imports nothing from the rest of ``repro`` (call-sites pass
 plain ints), so instrumenting ``models``/``kernels`` introduces no
 import cycles.
+
+``programs_traced()`` is the process-wide count of programs jax has traced
+(its ``jaxpr_trace_duration`` monitoring event, one listener registered on
+first use): a count that moves across a call means jit compiled a new
+specialization there, GEMMs or none.
 """
 from __future__ import annotations
 
 from contextlib import contextmanager
 
 _active = None
+_traced = 0
+_listening = False
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 
 
 def active():
@@ -43,6 +51,22 @@ def recording(recorder):
         yield recorder
     finally:
         _active = prev
+
+
+def _on_duration(event, duration, **_):
+    global _traced
+    if event == _TRACE_EVENT:
+        _traced += 1
+
+
+def programs_traced() -> int:
+    """Programs jax has traced in this process since the first call."""
+    global _listening
+    if not _listening:
+        import jax  # lazy: after launch._tpenv's device forcing
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+    return _traced
 
 
 class DispatchRecorder:
@@ -68,12 +92,6 @@ class DispatchRecorder:
             "fused/primitive Pallas kernel wrapper dispatches "
             "(counted once per compiled specialization)",
             labels=("kernel",))
-        self._compiles = registry.counter(
-            "jit_compiles_total",
-            "engine entry points whose call (re)traced — dispatch "
-            "counters moved during the call, i.e. jit compiled a new "
-            "specialization",
-            labels=("fn",))
 
     def gemm(self, backend: str, weight_bytes: int = 0) -> None:
         self._gemm.labels(backend=backend).inc()
@@ -82,18 +100,3 @@ class DispatchRecorder:
 
     def kernel(self, name: str) -> None:
         self._kernel.labels(kernel=name).inc()
-
-    def compiled(self, fn: str) -> None:
-        self._compiles.labels(fn=fn).inc()
-
-    def gemm_total(self) -> float:
-        """Sum of all qeinsum dispatch counts so far.
-
-        The counts only ever move while jax traces, so an engine can
-        snapshot this around a step's jitted call: a nonzero delta means
-        that call (re)compiled — the recompile tripwire behind
-        ``jit_compiles_total{fn=...}``."""
-        children = getattr(self._gemm, "_children", None)
-        if not children:
-            return 0.0
-        return sum(c.value for c in children.values())

@@ -12,7 +12,8 @@ Checks, exiting nonzero on any failure:
   * **span semantics** — per-lane B/E events balance (every span that
     opens closes, no cross-nesting), timestamps are non-decreasing, and
     the required lifecycle spans all occur: ``request``, ``queue``,
-    ``prefill``, ``decode``, ``engine.decode_step`` — plus ``spec.draft``
+    ``prefill``, ``decode``, ``engine.step``, ``engine.decode_step``,
+    ``engine.decode.wait`` — plus ``spec.draft``
     and ``spec.verify`` under ``--expect-spec``, and ``cache_lookup``
     (with the prefix-cache / preemption counters on the metrics side)
     under ``--expect-prefix-cache``;
@@ -28,8 +29,8 @@ import sys
 
 from .schema import load_schema, validate
 
-REQUIRED_SPANS = ("request", "queue", "prefill", "decode",
-                  "engine.decode_step")
+REQUIRED_SPANS = ("request", "queue", "prefill", "decode", "engine.step",
+                  "engine.decode_step", "engine.decode.wait")
 SPEC_SPANS = ("spec.draft", "spec.verify")
 # with --expect-prefix-cache: every admission probes the cache, so the
 # lookup span must occur; preempt/requeue spans only appear under actual

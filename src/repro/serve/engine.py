@@ -220,9 +220,6 @@ class Engine:
         self.prefill_tokens = 0
         self.decode_s = 0.0
         self.prefill_s = 0.0
-        # per-token decode latencies (step wall time amortized over the
-        # tokens that step emitted) — feeds the p50/p95 report
-        self.token_lat_s: list[float] = []
 
         # --- telemetry (repro.obs) -----------------------------------------
         # Instrument handles are bound ONCE here; the hot path only calls
@@ -286,6 +283,10 @@ class Engine:
         self._m_cached_blocks = m.gauge(
             "serve_cached_blocks",
             "unreferenced pool blocks retained by the prefix cache")
+        self._m_compiles = m.counter(
+            "jit_compiles_total",
+            "watched jitted calls during which jax traced a program, i.e. "
+            "compiled a new specialization", labels=("fn",))
         self._cache_seen = (0, 0)      # (hits, misses) already counted
         self.preempts = 0
         self._m_state_capacity.set(self.state.occupancy()[1])
@@ -309,9 +310,8 @@ class Engine:
             self.numerics = obs_numerics.NumericsRecorder(self.obs.metrics)
             self._shadow_fn = self._build_shadow()
 
-        # recompile tripwire: dispatch counters only move while jax traces,
-        # so a nonzero qeinsum-counter delta across the decode call means
-        # jit compiled a new specialization (see DispatchRecorder.gemm_total)
+        # recompile tripwire: jax.monitoring's trace events during a watched
+        # call mean jit compiled a new specialization (see _compile_watch)
         self._recompile_warned = False
         self._steady_after = 4          # decode steps before warning
 
@@ -390,10 +390,12 @@ class Engine:
         # first-trace qeinsum/kernel dispatches are attributed to this
         # engine (compiled replays never reach the recorder — see
         # repro.obs.dispatch)
-        if self.obs.dispatch is None:
-            return self._step_impl()
-        with obs_dispatch.recording(self.obs.dispatch):
-            return self._step_impl()
+        with self.obs.trace.annotate("engine.step",
+                                     n_active=len(self.sched.running())):
+            if self.obs.dispatch is None:
+                return self._step_impl()
+            with obs_dispatch.recording(self.obs.dispatch):
+                return self._step_impl()
 
     def _step_impl(self) -> list[Request]:
         finished: list[Request] = []
@@ -447,8 +449,14 @@ class Engine:
         d.update(self.state.stats())
         return d
 
+    def token_gaps(self) -> list[float]:
+        """Seconds between consecutive tokens of each finished request, from
+        the stamps ``_emit`` puts on ``Request.token_t``."""
+        return [b - a for r in self.sched.finished.values()
+                for a, b in zip(r.token_t, r.token_t[1:])]
+
     def _latency_stats(self) -> dict:
-        """Per-request TTFT and per-token decode latency percentiles.
+        """Per-request TTFT and inter-token gap percentiles.
 
         Empty populations report ``None`` (not 0.0) — "no data" and "zero
         latency" are different answers and exporters render them apart.
@@ -456,7 +464,7 @@ class Engine:
         ttfts = [r.ttft_s for r in self.sched.finished.values()
                  if r.first_tok_t]
         out = {}
-        for name, vals in (("ttft", ttfts), ("decode_lat", self.token_lat_s)):
+        for name, vals in (("ttft", ttfts), ("decode_lat", self.token_gaps())):
             out[f"{name}_p50_s"] = float(np.percentile(vals, 50)) \
                 if vals else None
             out[f"{name}_p95_s"] = float(np.percentile(vals, 95)) \
@@ -473,8 +481,6 @@ class Engine:
             req = self._in_flight_prefill()
             if req is None:
                 req = self._admit_next()
-                if req is not None:
-                    self._on_admit(req)
             if req is None:
                 break
             any_work = True
@@ -510,21 +516,32 @@ class Engine:
                 if self.obs.trace.enabled:
                     self.obs.trace.begin("decode", request_tid(req.rid))
             else:
-                self._emit(req, self._sample_one(req, logits), finished)
+                with self.obs.trace.annotate("engine.first_token",
+                                             rid=req.rid):
+                    self._emit(req, self._sample_one(req, logits), finished)
         dt = time.monotonic() - t0
         self.prefill_s += dt
         if any_work:
             self._m_prefill_step.observe(dt)
 
     def _admit_next(self) -> Request | None:
-        """Admit the queue head, under a ``cache_lookup`` span when the
-        prefix cache is live (admission is where the cache walk and hit
+        """Admit the queue head when a slot is free, under an
+        ``engine.admit`` span (slot choice, state reservation and the
+        admission hooks) and, when the prefix cache is live, a nested
+        ``cache_lookup`` span (admission is where the cache walk and hit
         acquisition happen, inside ``state.reserve``)."""
-        if not self.prefix_cache or not self.sched.waiting:
-            return self.sched.admit_next()
+        if not self.sched.waiting or self.sched.free_slot() is None:
+            return None
+        tr = self.obs.trace
         head = self.sched.waiting[0]
-        with self.obs.trace.annotate("cache_lookup", rid=head.rid):
-            req = self.sched.admit_next()
+        with tr.annotate("engine.admit", rid=head.rid):
+            if self.prefix_cache:
+                with tr.annotate("cache_lookup", rid=head.rid):
+                    req = self.sched.admit_next()
+            else:
+                req = self.sched.admit_next()
+            if req is not None:
+                self._on_admit(req)
         return req
 
     def _count_cache_evict(self, n: int) -> None:
@@ -687,63 +704,79 @@ class Engine:
         if self.kv_alloc != "ondemand":
             return reqs
         live = list(reqs)
-        for r in list(live):
-            while r in live and not self.state.grow_to(r, r.n_cached + 1):
-                victim = self.sched.preempt_victim()
-                assert victim is not None, "no preemption victim while growing"
-                self._preempt_one(victim)
-                if victim in live:
-                    live.remove(victim)
-        if extra:
-            for r in live:
-                self.state.grow_to(r, r.n_cached + 1 + extra)
+        with self.obs.trace.annotate("engine.decode.grow"):
+            for r in list(live):
+                while r in live and not self.state.grow_to(r,
+                                                           r.n_cached + 1):
+                    victim = self.sched.preempt_victim()
+                    assert victim is not None, \
+                        "no preemption victim while growing"
+                    self._preempt_one(victim)
+                    if victim in live:
+                        live.remove(victim)
+            if extra:
+                for r in live:
+                    self.state.grow_to(r, r.n_cached + 1 + extra)
         return live
 
     # -- decode ------------------------------------------------------------
 
     def _do_decode(self, finished: list[Request]) -> None:
+        """One batched decode over the running slots: inside the
+        ``engine.decode_step`` span build the host inputs, enqueue the
+        jitted step and the sampler and wait for the sampled tokens; then
+        emit them under ``engine.decode.emit``.  Emitting stays outside
+        the step's span, where it has always been, so the span measures
+        what it measured before it had children."""
         reqs = self.sched.running()
         if reqs:
             reqs = self._ensure_decode_capacity(reqs)
         if not reqs:
             return
+        tr = self.obs.trace
         t0 = time.monotonic()
-        ns = self.n_slots
-        toks = np.zeros((ns, 1), np.int32)
-        lens = np.zeros((ns,), np.int32)
-        active = np.zeros((ns,), bool)
-        temps = np.zeros((ns,), np.float32)
-        topks = np.zeros((ns,), np.int32)
-        seeds = np.zeros((ns,), np.int32)
-        idxs = np.zeros((ns,), np.int32)
-        for r in reqs:
-            s = r.slot
-            toks[s, 0] = r.next_input_token()
-            lens[s] = r.n_cached
-            active[s] = True
-            temps[s] = r.sampling.temperature
-            topks[s] = r.sampling.top_k
-            seeds[s] = r.sampling.seed
-            idxs[s] = len(r.output)
-        with self.obs.trace.annotate("engine.decode_step",
-                                     n_active=len(reqs)):
-            logits = self._compile_watch(
-                "decode", lambda: self.state.decode(reqs, toks, lens, active))
-            sampled = np.asarray(self._sample(logits[:, 0, :],
-                                              jnp.asarray(temps),
-                                              jnp.asarray(topks),
-                                              jnp.asarray(seeds),
-                                              jnp.asarray(idxs)))
+        with tr.annotate("engine.decode_step", n_active=len(reqs)):
+            with tr.annotate("engine.decode.inputs"):
+                ns = self.n_slots
+                toks = np.zeros((ns, 1), np.int32)
+                lens = np.zeros((ns,), np.int32)
+                active = np.zeros((ns,), bool)
+                temps = np.zeros((ns,), np.float32)
+                topks = np.zeros((ns,), np.int32)
+                seeds = np.zeros((ns,), np.int32)
+                idxs = np.zeros((ns,), np.int32)
+                for r in reqs:
+                    s = r.slot
+                    toks[s, 0] = r.next_input_token()
+                    lens[s] = r.n_cached
+                    active[s] = True
+                    temps[s] = r.sampling.temperature
+                    topks[s] = r.sampling.top_k
+                    seeds[s] = r.sampling.seed
+                    idxs[s] = len(r.output)
+                bt = self.state.block_table(reqs)
+            with tr.annotate("engine.decode.dispatch"):
+                logits = self._compile_watch(
+                    "decode",
+                    lambda: self.state.decode(reqs, toks, lens, active, bt))
+            with tr.annotate("engine.decode.sample"):
+                sampled = self._compile_watch(
+                    "sample", lambda: self._sample(
+                        logits[:, 0, :], jnp.asarray(temps),
+                        jnp.asarray(topks), jnp.asarray(seeds),
+                        jnp.asarray(idxs)))
+            with tr.annotate("engine.decode.wait"):
+                sampled = np.asarray(sampled)
         dt = time.monotonic() - t0
         self._note_decode_step(dt, len(reqs))
         self.decode_tokens += len(reqs)
         self._m_tok_decode.inc(len(reqs))
-        self.token_lat_s.extend([dt] * len(reqs))
-        for r in reqs:
-            r.n_cached += 1
-            r.n_written = max(r.n_written, r.n_cached)
-            self._emit(r, self._chosen(r, int(sampled[r.slot]),
-                                       logits[r.slot, 0]), finished)
+        with tr.annotate("engine.decode.emit", n_tokens=len(reqs)):
+            for r in reqs:
+                r.n_cached += 1
+                r.n_written = max(r.n_written, r.n_cached)
+                self._emit(r, self._chosen(r, int(sampled[r.slot]),
+                                           logits[r.slot, 0]), finished)
 
     # -- shared ------------------------------------------------------------
 
@@ -767,19 +800,17 @@ class Engine:
     def _compile_watch(self, fn_name: str, thunk):
         """Run ``thunk`` watching for a (re)compile of its jitted call.
 
-        The qeinsum dispatch counters advance only while jax TRACES, so a
-        delta across the call means jit compiled a new specialization:
-        count it under ``jit_compiles_total{fn=...}`` and — once, past
-        warmup — warn that the steady-state loop is retracing (a shape or
-        dtype leak into a traced argument, the classic silent perf cliff).
+        jax traces a program only to compile a new specialization, so a
+        trace event during the call (``obs_dispatch.programs_traced``)
+        means it compiled: count it under ``jit_compiles_total{fn=...}``
+        and — once, past warmup — warn that the steady-state loop is
+        retracing (a shape or dtype leak into a traced argument, the
+        classic silent perf cliff).
         """
-        rec = self.obs.dispatch
-        if rec is None:
-            return thunk()
-        before = rec.gemm_total()
+        before = obs_dispatch.programs_traced()
         out = thunk()
-        if rec.gemm_total() > before:
-            rec.compiled(fn_name)
+        if obs_dispatch.programs_traced() > before:
+            self._m_compiles.labels(fn=fn_name).inc()
             if self.decode_steps >= self._steady_after \
                     and not self._recompile_warned:
                 self._recompile_warned = True
@@ -898,17 +929,18 @@ class Engine:
     def _emit(self, req: Request, tok: int, finished: list[Request]) -> None:
         req.output.append(tok)
         self.tokens_generated += 1
+        now = time.monotonic()
+        req.token_t.append(now)
         tr = self.obs.trace
         if not req.first_tok_t:
-            req.first_tok_t = req.last_tok_t = time.monotonic()
+            req.first_tok_t = req.last_tok_t = now
             self._m_ttft.observe(req.ttft_s)
             if tr.enabled:
                 tid = request_tid(req.rid)
                 tr.instant("first_token", tid, token=tok,
                            ttft_s=req.ttft_s)
                 tr.begin("decode", tid)
-        elif self.obs.metrics.enabled:
-            now = time.monotonic()
+        else:
             self._m_itl.observe(now - req.last_tok_t)
             req.last_tok_t = now
         if self.eos_id is not None and tok == self.eos_id:

@@ -85,6 +85,9 @@ class Request:
     admit_t: float = 0.0                  # scheduler-stamped at admission
     first_tok_t: float = 0.0              # 0 until the first token emits
     last_tok_t: float = 0.0               # newest emission (inter-token lat)
+    token_t: list = dataclasses.field(default_factory=list)
+    #                                       the time each output token was
+    #                                       emitted (true inter-token gaps)
     finish_t: float = 0.0
     # ONE wall-clock anchor per request (time.time at submit), kept solely
     # so trace export / logs can place the request in absolute time

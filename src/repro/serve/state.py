@@ -18,7 +18,7 @@ Both answer the same contract the engine and scheduler program against:
 
     admission_check / can_reserve / reserve / release      (alloc + free)
     write_prefill                                          (prefill_write)
-    decode                                                 (decode_step)
+    block_table / decode                                   (decode_step)
     snapshot / restore_select / rollback_to / draft_cap    (speculative)
     stats / leaked                                         (telemetry)
 
@@ -328,11 +328,16 @@ class PagedKVState:
         self.pool.data = self._write_fns[p](self.pool.data, cache,
                                             jnp.asarray(ids))
 
-    def decode(self, reqs, toks, lens, active):
-        ns, mb = lens.shape[0], self.max_blocks_per_slot
-        bt = np.zeros((ns, mb), np.int32)
+    def block_table(self, reqs):
+        """The decode step's host input beside the per-slot arrays: each
+        slot's block ids, [n_slots, max_blocks_per_slot]."""
+        bt = np.zeros((self.eng.n_slots, self.max_blocks_per_slot), np.int32)
         for r in reqs:
             bt[r.slot, : len(r.block_ids)] = r.block_ids
+        return bt
+
+    def decode(self, reqs, toks, lens, active, bt):
+        del reqs                               # ``bt`` holds their blocks
         logits, self.pool.data = self._decode_fn(
             self.eng.params, self.pool.data, jnp.asarray(bt),
             jnp.asarray(lens), jnp.asarray(active), jnp.asarray(toks))
@@ -465,8 +470,12 @@ class SlabState:
         self.data = self._write_fns[p](self.data, cache,
                                        jnp.asarray(req.slot, jnp.int32))
 
-    def decode(self, reqs, toks, lens, active):
-        del reqs                               # slot index == state address
+    def block_table(self, reqs):
+        """Slabs need no host input beyond the per-slot arrays."""
+        return None
+
+    def decode(self, reqs, toks, lens, active, bt=None):
+        del reqs, bt                           # slot index == state address
         logits, self.data = self._decode_fn(
             self.eng.params, self.data, jnp.asarray(toks),
             jnp.asarray(lens), jnp.asarray(active))

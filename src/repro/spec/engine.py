@@ -227,7 +227,7 @@ class SpecEngine(Engine):
             prev_tok=prev, draft_lens=draft_lens, temps=temps, topks=topks,
             seeds=seeds, tok_idx=idxs)
 
-    def _account_round(self, reqs, out_toks, n_emit, n_acc, k_eff, dt,
+    def _account_round(self, reqs, out_toks, n_emit, n_acc, k_eff,
                        finished):
         """Advance requests by their ACCEPTED tokens; returns per-slot
         (emitted-count, confirmed-draft-advance) arrays for the slab path's
@@ -261,9 +261,6 @@ class SpecEngine(Engine):
             adv[s] = min(j + 1, ke)
             self.decode_tokens += len(toks_emit)
             self._m_tok_decode.inc(len(toks_emit))
-            # a request that got n tokens this step experienced dt/n per
-            # token (the plain engine's dt-per-token at n == 1)
-            self.token_lat_s.extend([dt / len(toks_emit)] * len(toks_emit))
             for tok in toks_emit:
                 self._emit(r, tok, finished)
             if r.done:
@@ -280,31 +277,35 @@ class SpecEngine(Engine):
             reqs = self._ensure_decode_capacity(reqs, extra=self.spec_k)
         if not reqs:
             return
+        tr = self.obs.trace
         t0 = time.monotonic()
         # the whole draft/verify round IS this engine's decode step — the
-        # engine-lane span name is shared with the plain engine so one
+        # engine-lane span names are shared with the plain engine so one
         # trace schema covers both (spec.* spans nest inside it)
-        with self.obs.trace.span("engine.decode_step", n_active=len(reqs)):
-            st = self._round_state(reqs)
-            with self.obs.trace.annotate("spec.draft", n_active=len(reqs),
-                                         k=self.spec_k):
+        with tr.annotate("engine.decode_step", n_active=len(reqs)):
+            with tr.annotate("engine.decode.inputs"):
+                st = self._round_state(reqs)
+            with tr.annotate("spec.draft", n_active=len(reqs),
+                             k=self.spec_k):
                 draft_toks, draft_probs = self.proposer.propose(st,
                                                                 self.spec_k)
             t_draft = time.monotonic() - t0
 
             tokens = np.concatenate([st.last_tok[:, None], draft_toks],
                                     axis=1)
-            with self.obs.trace.annotate("spec.verify", n_active=len(reqs)):
+            with tr.annotate("spec.verify", n_active=len(reqs)):
                 logits, self.pool.data = self._compile_watch(
                     "verify", lambda: self._verify(
                         self.params, self.pool.data, jnp.asarray(st.bt),
                         jnp.asarray(st.lens), jnp.asarray(st.active),
                         jnp.asarray(st.k_eff), jnp.asarray(tokens)))
-                out_toks, n_emit, n_acc = map(np.asarray, self._accept(
+                accepted = self._accept(
                     logits, jnp.asarray(draft_toks),
                     jnp.asarray(draft_probs), jnp.asarray(st.k_eff),
                     jnp.asarray(st.temps), jnp.asarray(st.topks),
-                    jnp.asarray(st.seeds), jnp.asarray(st.tok_idx)))
+                    jnp.asarray(st.seeds), jnp.asarray(st.tok_idx))
+                with tr.annotate("engine.decode.wait"):
+                    out_toks, n_emit, n_acc = map(np.asarray, accepted)
 
             dt = time.monotonic() - t0
             self._observe_costs(t_draft, dt - t_draft,
@@ -314,8 +315,9 @@ class SpecEngine(Engine):
             self._m_verify_s.observe(dt - t_draft)
             self.verify_steps += 1
             self.verify_slot_rounds += len(reqs)
-            self._account_round(reqs, out_toks, n_emit, n_acc, st.k_eff, dt,
-                                finished)
+            with tr.annotate("engine.decode.emit", n_tokens=int(n_emit.sum())):
+                self._account_round(reqs, out_toks, n_emit, n_acc, st.k_eff,
+                                    finished)
 
     def _do_decode_stepped(self, finished: list[Request]) -> None:
         """Slab-state round: sequential stepped verify + snapshot/restore.
@@ -333,12 +335,13 @@ class SpecEngine(Engine):
         reqs = self.sched.running()
         if not reqs:
             return
+        tr = self.obs.trace
         t0 = time.monotonic()
         ns, k = self.n_slots, self.spec_k
-        with self.obs.trace.span("engine.decode_step", n_active=len(reqs)):
-            st = self._round_state(reqs)
-            with self.obs.trace.annotate("spec.draft", n_active=len(reqs),
-                                         k=k):
+        with tr.annotate("engine.decode_step", n_active=len(reqs)):
+            with tr.annotate("engine.decode.inputs"):
+                st = self._round_state(reqs)
+            with tr.annotate("spec.draft", n_active=len(reqs), k=k):
                 draft_toks, draft_probs = self.proposer.propose(st, k)
             t_draft = time.monotonic() - t0
 
@@ -346,7 +349,7 @@ class SpecEngine(Engine):
                                     axis=1)
             logits = np.zeros((ns, k + 1, self.cfg.vocab_size), np.float32)
             snaps = [self.state.snapshot()]
-            with self.obs.trace.annotate("spec.verify", n_active=len(reqs)):
+            with tr.annotate("spec.verify", n_active=len(reqs)):
                 for i in range(k + 1):
                     act_i = st.active & (i <= st.k_eff)
                     lg = self._compile_watch(
@@ -354,11 +357,13 @@ class SpecEngine(Engine):
                             reqs, tokens[:, i:i + 1], st.lens + i, act_i))
                     logits[:, i] = np.asarray(lg[:, 0, :], np.float32)
                     snaps.append(self.state.snapshot())
-                out_toks, n_emit, n_acc = map(np.asarray, self._accept(
+                accepted = self._accept(
                     jnp.asarray(logits), jnp.asarray(draft_toks),
                     jnp.asarray(draft_probs), jnp.asarray(st.k_eff),
                     jnp.asarray(st.temps), jnp.asarray(st.topks),
-                    jnp.asarray(st.seeds), jnp.asarray(st.tok_idx)))
+                    jnp.asarray(st.seeds), jnp.asarray(st.tok_idx))
+                with tr.annotate("engine.decode.wait"):
+                    out_toks, n_emit, n_acc = map(np.asarray, accepted)
 
             dt = time.monotonic() - t0
             self._observe_costs(t_draft, dt - t_draft,
@@ -368,11 +373,12 @@ class SpecEngine(Engine):
             self._m_verify_s.observe(dt - t_draft)
             self.verify_steps += 1
             self.verify_slot_rounds += len(reqs)
-            sel, adv = self._account_round(reqs, out_toks, n_emit, n_acc,
-                                           st.k_eff, dt, finished)
+            with tr.annotate("engine.decode.emit", n_tokens=int(n_emit.sum())):
+                sel, adv = self._account_round(reqs, out_toks, n_emit, n_acc,
+                                               st.k_eff, finished)
             # lossless rollback: every slot's state becomes exactly the
             # state after its emitted tokens — bitwise, never having drafted
-            with self.obs.trace.span("spec.rollback", n_active=len(reqs)):
+            with tr.annotate("spec.rollback", n_active=len(reqs)):
                 self.state.restore_select(snaps, sel)
                 self.proposer.commit(adv)
 

@@ -328,3 +328,119 @@ def test_engine_metrics_off_allocates_no_instruments(loaded):
     _run(eng, _prompts(cfg, MIXED_LENS[:2]))
     assert eng.obs.metrics.snapshot() == {}
     assert eng.obs.trace.events == ()
+
+
+def test_noop_tracer_allocates_nothing_per_call():
+    """Every span of the disabled tracer is the one shared null object, and
+    ten thousand of them (with args, as the engine passes them) leave no
+    allocation behind."""
+    import tracemalloc
+
+    ctx = NOOP_TRACER.annotate("engine.decode_step", n_active=3)
+    assert ctx is NOOP_TRACER.annotate("engine.decode.wait")
+    assert ctx is NOOP_TRACER.span("engine.step")
+    tracemalloc.start()
+    try:
+        before = tracemalloc.get_traced_memory()[0]
+        for i in range(10_000):
+            with NOOP_TRACER.annotate("engine.decode.emit", n_tokens=i):
+                pass
+        after = tracemalloc.get_traced_memory()[0]
+    finally:
+        tracemalloc.stop()
+    assert after - before < 100            # the loop's last int, at most
+    assert NOOP_TRACER.events == ()
+
+
+def _engine_lane(doc):
+    return [e for e in doc["traceEvents"]
+            if e["tid"] == 0 and e["ph"] in "BE"]
+
+
+def test_engine_span_tree_nests_inside_the_step(loaded):
+    """On the engine lane every span opens and closes inside an
+    ``engine.step``; each ``engine.decode_step`` holds its children in
+    order, one wait among them, and its tokens' ``engine.decode.emit``
+    follows it."""
+    cfg, params, qcfg = loaded
+    obs = Observability(metrics=False, trace=True)
+    eng = _engine(cfg, params, qcfg, obs=obs)
+    _run(eng, _prompts(cfg, MIXED_LENS))
+    lane = _engine_lane(obs.trace.to_chrome())
+    stack, children, n_steps = [], [], 0
+    for e in lane:
+        if e["ph"] == "B":
+            if e["name"] != "engine.step":
+                assert stack and stack[0] == "engine.step", e["name"]
+            if e["name"].startswith("engine.decode."):
+                assert stack[-1] == ("engine.step"
+                                     if e["name"] == "engine.decode.emit"
+                                     else "engine.decode_step"), e["name"]
+                children.append(e["name"])
+            if e["name"] == "engine.decode_step":
+                n_steps += 1
+            stack.append(e["name"])
+        else:
+            assert stack.pop() == e["name"]
+    assert stack == []
+    assert n_steps == eng.decode_steps > 0
+    step = ["engine.decode.inputs", "engine.decode.dispatch",
+            "engine.decode.sample", "engine.decode.wait",
+            "engine.decode.emit"]
+    assert children == step * n_steps
+    admits = [e for e in lane if e["name"] == "engine.admit"
+              and e["ph"] == "B"]
+    firsts = [e for e in lane if e["name"] == "engine.first_token"
+              and e["ph"] == "B"]
+    assert len(admits) == len(firsts) == len(MIXED_LENS)
+    assert {e["args"]["rid"] for e in admits} == set(range(len(MIXED_LENS)))
+
+
+def test_token_stamps_give_the_inter_token_gaps(loaded):
+    """Each emitted token is stamped on its request; the decode latency
+    percentiles are those of the gaps between consecutive stamps."""
+    cfg, params, qcfg = loaded
+    eng = _engine(cfg, params, qcfg)
+    _run(eng, _prompts(cfg, MIXED_LENS))
+    gaps = []
+    for r in eng.sched.finished.values():
+        assert len(r.token_t) == len(r.output) == GEN
+        assert r.token_t[0] == r.first_tok_t and r.token_t[-1] == r.last_tok_t
+        assert r.token_t == sorted(r.token_t)
+        gaps += list(np.diff(r.token_t))
+    assert sorted(eng.token_gaps()) == sorted(gaps)
+    st = eng.stats()
+    assert st["decode_lat_p50_s"] == pytest.approx(np.percentile(gaps, 50))
+    assert st["decode_lat_p95_s"] == pytest.approx(np.percentile(gaps, 95))
+
+
+def test_compile_watch_counts_programs_without_gemms(loaded, capsys):
+    """The watch counts a jitted call that traced, whatever it holds: the
+    sampler has no GEMM.  A retrace past warm-up warns once."""
+    from repro.obs import dispatch as obs_dispatch
+
+    n = obs_dispatch.programs_traced()
+    f = jax.jit(lambda x: x + 1)
+    f(np.zeros(3, np.float32))
+    assert obs_dispatch.programs_traced() > n
+    n = obs_dispatch.programs_traced()
+    f(np.ones(3, np.float32))                       # compiled: no trace
+    assert obs_dispatch.programs_traced() == n
+
+    cfg, params, qcfg = loaded
+    # a slot count no other engine of this module uses: jit shares traced
+    # programs across engines, so only a new shape traces here
+    eng = _engine(cfg, params, qcfg, obs=Observability(metrics=True),
+                  n_slots=3)
+    _run(eng, _prompts(cfg, MIXED_LENS))
+    comp = eng.obs.metrics.get("jit_compiles_total").snapshot()
+    fns = {c["labels"]["fn"]: c["value"] for c in comp["labels"]}
+    assert fns["decode"] >= 1 and fns["sample"] >= 1
+    # a new specialization past warm-up: one count and one warning
+    eng.decode_steps = 10
+    eng._compile_watch("sample", lambda: jax.jit(lambda x: x * 2)(1.0))
+    eng._compile_watch("sample", lambda: jax.jit(lambda x: x * 3)(1.0))
+    comp = eng.obs.metrics.get("jit_compiles_total").snapshot()
+    after = {c["labels"]["fn"]: c["value"] for c in comp["labels"]}
+    assert after["sample"] == fns["sample"] + 2
+    assert capsys.readouterr().err.count("recompiled") == 1

@@ -103,3 +103,45 @@ def test_chunked_loss_trains_equivalently(teacher):
     _, m_chunk = mk(True)(s0, b)
     np.testing.assert_allclose(float(m_plain["kl"]), float(m_chunk["kl"]),
                                rtol=5e-2, atol=1e-4)
+
+
+def _scoped_step_and_state():
+    model = get_model(CFG)
+    opt = AdamW(lr=1e-3, clip_norm=1.0)
+    state = qad.init_state(model, CFG, jax.random.PRNGKey(1), opt)
+    return model, opt, state
+
+
+def test_step_hlo_carries_every_phase_scope():
+    """Each phase's ops carry its named scope in the compiled module's
+    op_names; the student's backward under ``transpose(``."""
+    model, opt, state = _scoped_step_and_state()
+    step = jax.jit(qad.make_train_step(model, CFG, QCFG, opt))
+    text = step.lower(state, make_batch(DCFG, 0)).compile().as_text()
+    assert qad.PHASES == ("qad.teacher", "qad.student", "qad.kl",
+                          "qad.optimizer")
+    for scope in qad.PHASES + ("transpose(jvp(qad.student))",):
+        assert scope in text, scope
+
+
+def test_phase_scopes_leave_the_step_bitwise_unchanged(monkeypatch):
+    """Scopes are metadata: three steps with them and three steps of the
+    same step built with ``jax.named_scope`` stripped give bitwise equal
+    states and losses."""
+    import contextlib
+
+    def three_steps():
+        model, opt, state = _scoped_step_and_state()
+        step = jax.jit(qad.make_train_step(model, CFG, QCFG, opt))
+        losses = []
+        for i in range(3):
+            state, m = step(state, make_batch(DCFG, 500 + i))
+            losses.append(m["loss"])
+        return state, losses
+
+    scoped = three_steps()
+    with monkeypatch.context() as mp:
+        mp.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        stripped = three_steps()
+    for a, b in zip(jax.tree.leaves(scoped), jax.tree.leaves(stripped)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
