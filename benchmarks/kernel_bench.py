@@ -60,10 +60,11 @@ def kernels():
     # fused one-pass paged attention vs the gather+dequant two-step.
     # decode geometry: 4 slots, 8 blocks x 16 tokens, GQA 8q/2kv, hd=64
     b, mb, bs, hkv, n_rep, hd = 4, 8, 16, 2, 4, 64
-    kp = jax.random.normal(rng, (b * mb, bs, hkv, hd)).astype(jnp.bfloat16)
+    kp = jax.random.normal(rng, (1, b * mb, bs, hkv * hd)
+                           ).astype(jnp.bfloat16)
     vp = jax.random.normal(jax.random.fold_in(rng, 2),
-                           (b * mb, bs, hkv, hd)).astype(jnp.bfloat16)
-    pool = {"k": kp, "v": vp}
+                           (1, b * mb, bs, hkv * hd)).astype(jnp.bfloat16)
+    pool = {"k": kp, "v": vp}               # one layer, the stored layout
     bt = jnp.arange(b * mb, dtype=jnp.int32).reshape(b, mb)
     pos = jnp.full((b,), mb * bs, jnp.int32)
     q = jax.random.normal(jax.random.fold_in(rng, 3),
@@ -72,10 +73,11 @@ def kernels():
     # materializes (written + re-read by the two-step, in HBM on TPU)
     gather_bytes = 2 * 2 * b * mb * bs * hkv * hd * 2
     case = f"{b}x{mb * bs}kv_h{hkv}x{n_rep}_hd{hd}"
-    us = _time(jax.jit(lambda a: attn.paged_attend_fused(a, pool, bt, pos)), q)
+    us = _time(jax.jit(
+        lambda a: attn.paged_attend_fused(a, pool, 0, bt, pos)), q)
     emit(f"kernel/paged_attention_fused_{case}", us,
          f"one_pass;gather_intermediate_bytes_avoided={gather_bytes}")
-    us = _time(jax.jit(lambda a: attn.paged_attend(a, pool, bt, pos)), q)
+    us = _time(jax.jit(lambda a: attn.paged_attend(a, pool, 0, bt, pos)), q)
     emit(f"kernel/paged_attention_gather_{case}", us,
          f"gather_dequant_baseline;intermediate_bytes={gather_bytes}")
 

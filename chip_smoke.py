@@ -232,10 +232,12 @@ def phase_kernels():
     n_blocks = b * mb + 2
     for s_q in (1, 4):                    # decode, and k+1 verify with k=3
         kk = jax.random.fold_in(key, 100 + s_q)
+        # two layers of the stored pool layout; the kernel reads layer 1
         pool = {name: jax.random.normal(jax.random.fold_in(kk, j),
-                                        (n_blocks, bs, nkv, hd), f32
+                                        (2, n_blocks, bs, nkv * hd), f32
                                         ).astype(jnp.bfloat16)
                 for j, name in enumerate(("k", "v"))}
+        layer = jnp.int32(1)
         bt = jax.random.permutation(jax.random.fold_in(kk, 2), n_blocks
                                     )[: b * mb].reshape(b, mb).astype(
                                         jnp.int32)
@@ -246,17 +248,18 @@ def phase_kernels():
                                          jnp.int32)
         q = jax.random.normal(jax.random.fold_in(kk, 4),
                               (b, s_q, nh, hd)).astype(jnp.bfloat16)
-        compiled, mosaic = lowered(attn.paged_attend_fused, q, pool, bt, pos)
-        got = np.asarray(compiled(q, pool, bt, pos), np.float32)
+        compiled, mosaic = lowered(attn.paged_attend_fused, q, pool, layer,
+                                   bt, pos)
+        got = np.asarray(compiled(q, pool, layer, bt, pos), np.float32)
         oracle = jax.jit(attn.paged_attend)
-        want = np.asarray(oracle(q, pool, bt, pos), np.float32)
+        want = np.asarray(oracle(q, pool, layer, bt, pos), np.float32)
         # the oracle's one-query dots are lowered by XLA as multiply+reduce,
         # the kernel's on the MXU: they may differ by the bf16 roundings of
         # p and of the output, each within 2^-8 of sum_i p_i |v_i| (that
         # sum is the oracle attending over |v|).  With k+1 queries both
         # sides use the MXU and must agree bit for bit.
-        mag = np.asarray(oracle(q, dict(pool, v=jnp.abs(pool["v"])), bt,
-                                pos), np.float32)
+        mag = np.asarray(oracle(q, dict(pool, v=jnp.abs(pool["v"])), layer,
+                                bt, pos), np.float32)
         ratio = float(np.max(np.abs(got - want) / (2**-7 * mag + 1e-30)))
         good = mosaic and (ratio <= 1.0 if s_q == 1 else ratio == 0.0)
         ok &= good

@@ -94,9 +94,11 @@ def nvfp4_matmul_tp(x: jax.Array, packed: PackedNVFP4, mesh,
         return _nvfp4_matmul_tp(x, packed, mesh, parallelism, **kw)
 
 
-def paged_attention(q: jax.Array, pool_sl: dict, block_tables: jax.Array,
-                    pos: jax.Array, *, window: int = 0, **kw) -> jax.Array:
-    """Fused page-gather + FP8-dequant + attend over a paged-pool layer.
+def paged_attention(q: jax.Array, pool: dict, layer: jax.Array,
+                    block_tables: jax.Array, pos: jax.Array, *,
+                    window: int = 0, **kw) -> jax.Array:
+    """Fused page-gather + FP8-dequant + attend over layer ``layer`` of the
+    whole paged pool (read in place: no layer slice is materialized).
 
     Drop-in for the ``paged_gather_layer`` -> ``paged_attend`` two-step
     (``models.attention``), which remains its parity oracle — bitwise for
@@ -105,9 +107,9 @@ def paged_attention(q: jax.Array, pool_sl: dict, block_tables: jax.Array,
     kw.setdefault("interpret", interpret_default())
     _note("paged_attention")
     with jax.named_scope("repro.paged_attention"):
-        return _paged_attention(q, pool_sl["k"], pool_sl["v"], block_tables,
-                                pos, pool_sl.get("k_scale"),
-                                pool_sl.get("v_scale"), window=window, **kw)
+        return _paged_attention(q, pool["k"], pool["v"], block_tables, pos,
+                                layer, pool.get("k_scale"),
+                                pool.get("v_scale"), window=window, **kw)
 
 
 def dequant_weight(packed: PackedNVFP4, contract_axis: int,

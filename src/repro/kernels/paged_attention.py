@@ -1,19 +1,26 @@
 """Pallas TPU kernel: fused paged attention for the serving decode path.
 
+The pool is stored as the kernel reads it: K and V pages
+``[L, n_blocks, bs, Hkv*hd]`` (a row-major flat head axis, so a head's
+``hd`` lanes stay together), FP8 scale planes ``[L, n_blocks, bs, Hkv]``.
+The kernel takes the WHOLE pool plus a layer index, so the decode step can
+carry the pool through its layer scan and update it in place: no layer
+slice, no relayout and no second pool is ever materialized for the call.
+
 The unfused hot path (``models.attention``) is a two-step:
 
-    paged_gather_layer   — gather pool pages [n_blocks, bs, Hkv, hd] into a
-                           dense per-request view [B, MB*bs, Hkv, hd],
+    paged_gather_layer   — gather the layer's pool pages into a dense
+                           per-request view [B, MB*bs, Hkv, hd],
                            dequantizing FP8 pages to BF16 on the way
     paged_attend         — repeat_kv + score/softmax/weighted-sum einsums
 
 which materializes the gathered KV in HBM (reads every page, writes a dense
 copy, reads it again) and runs the FP8 dequant as a separate elementwise
 pass.  This kernel does page-table gather + FP8-KV dequant + attend in ONE
-``pallas_call`` over the block table: the per-request block table rides in
-as a scalar-prefetch operand, so each grid step's ``BlockSpec`` index map
-computes the page to DMA next — pages stream HBM→VMEM exactly once and the
-dense intermediate never exists.
+``pallas_call`` over the block table: the layer index and the per-request
+block table ride in as scalar-prefetch operands, so each grid step's
+``BlockSpec`` index map computes the page to DMA next — pages stream
+HBM→VMEM exactly once and the dense intermediate never exists.
 
 Both serving shapes share the kernel:
 
@@ -40,12 +47,15 @@ when the strip is too big for VMEM — the prefill regime, which
 
 Mosaic layout: a block's two minor dims must be (8, 128)-aligned or whole,
 so a grid step reads a page's [bs, g*hd] slab for the g KV heads that fill
-128 lanes (the pool viewed as [n_blocks, bs, Hkv*hd]) and buffers it whole
-in a [s_alloc, g*hd] scratch strip, so no lane is padding at hd 64.  The
-strips and the score and probability rows must fit Mosaic's scoped VMEM
-(``vmem_bytes``): at qwen1.5-0.5b's heads that holds to 24k context, and
-32k does not compile, so ``serve.Engine`` falls back to the two-step where
-``fits_vmem`` says no.
+128 lanes, and buffers it whole in a [s_alloc, g*hd] scratch strip, so no
+lane is padding at hd 64.  The pool's flat head axis is what makes that
+slab a plain block of the stored array: with a separate [.., Hkv, hd]
+minor pair at hd 64 the TPU tiles the pool differently, and viewing a
+layer as [n_blocks, bs, Hkv*hd] cost a relayout of the whole layer, for K
+and for V, in every layer of every step.  The strips and the score and
+probability rows must fit Mosaic's scoped VMEM (``vmem_bytes``): at
+qwen1.5-0.5b's heads that holds to 24k context, and 32k does not compile,
+so ``serve.Engine`` falls back to the two-step where ``fits_vmem`` says no.
 """
 from __future__ import annotations
 
@@ -88,10 +98,11 @@ def fits_vmem(s_alloc: int, hkv: int, hd: int, rows: int) -> bool:
     return vmem_bytes(s_alloc, hkv, hd, rows) <= SCOPED_VMEM_BYTES
 
 
-def _attend_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                   o_ref, k_scr, v_scr, *, mb: int, bs: int, s_q: int,
-                   g: int, window: int, fp8: bool):
-    """grid (B, Hkv/g, MB); page j arrives via the scalar-prefetched table.
+def _attend_kernel(bt_ref, pos_ref, layer_ref, q_ref, k_ref, v_ref, ks_ref,
+                   vs_ref, o_ref, k_scr, v_scr, *, mb: int, bs: int,
+                   s_q: int, g: int, window: int, fp8: bool):
+    """grid (B, Hkv/g, MB); page j of the prefetched layer arrives via the
+    scalar-prefetched table (``layer_ref`` is read by the index maps only).
 
     Each step buffers its page's K and V for the step's g heads; the last
     step attends over the whole buffered strip in the oracle's op order.
@@ -145,18 +156,20 @@ def _attend_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     block_tables: jax.Array, pos: jax.Array,
-                    k_scale: jax.Array | None = None,
+                    layer: jax.Array, k_scale: jax.Array | None = None,
                     v_scale: jax.Array | None = None, *,
                     window: int = 0, interpret: bool = True) -> jax.Array:
     """Fused gather+dequant+attend; drop-in for the gather/attend two-step.
 
-    q: [B, S, H, hd]; pages: [n_blocks, bs, Hkv, hd] (+ optional fp32
-    [n_blocks, bs, Hkv] scale planes for FP8 pools); block_tables: [B, MB];
-    pos: [B] or [B, S] per-query valid-key counts, ``paged_attend``
-    semantics.  Returns [B, S, H, hd] in q's dtype.
+    q: [B, S, H, hd]; pages: the whole pool, [L, n_blocks, bs, Hkv*hd]
+    (+ optional fp32 [L, n_blocks, bs, Hkv] scale planes for FP8 pools);
+    block_tables: [B, MB]; pos: [B] or [B, S] per-query valid-key counts,
+    ``paged_attend`` semantics; layer: the (traced) layer index the pages
+    are read from.  Returns [B, S, H, hd] in q's dtype.
     """
     b, s_q, h, hd = q.shape
-    n_blocks, bs, hkv, _ = k_pages.shape
+    bs = k_pages.shape[2]
+    hkv = k_pages.shape[3] // hd
     mb = block_tables.shape[1]
     n_rep = h // hkv
     r = n_rep * s_q
@@ -169,30 +182,31 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     pos = jnp.asarray(pos, jnp.int32)
     pos2 = jnp.broadcast_to(pos[:, None] if pos.ndim == 1 else pos, (b, s_q))
     bt = jnp.asarray(block_tables, jnp.int32)
-    # a page's heads side by side on the lanes (a row-major reshape)
-    k_flat = k_pages.reshape(n_blocks, bs, hkv * hd)
-    v_flat = v_pages.reshape(n_blocks, bs, hkv * hd)
+    lyr = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
-    def page_map(bi, hi, ji, bt, pos):
-        return (bt[bi, ji], 0, hi)
+    def page_map(bi, hi, ji, bt, pos, lyr):
+        return (lyr[0], bt[bi, ji], 0, hi)
 
-    def scale_map(bi, hi, ji, bt, pos):
-        return (bt[bi, ji], 0, 0)
+    def scale_map(bi, hi, ji, bt, pos, lyr):
+        return (lyr[0], bt[bi, ji], 0, 0)
 
+    def q_map(bi, hi, ji, bt, pos, lyr):
+        return (bi, hi, 0, 0)
+
+    # the layer dim is squeezed: the kernel sees one page, [1, bs, g*hd]
     in_specs = [
-        pl.BlockSpec((1, g, r, hd),
-                     lambda bi, hi, ji, bt, pos: (bi, hi, 0, 0)),
-        pl.BlockSpec((1, bs, g * hd), page_map),
-        pl.BlockSpec((1, bs, g * hd), page_map),
+        pl.BlockSpec((1, g, r, hd), q_map),
+        pl.BlockSpec((None, 1, bs, g * hd), page_map),
+        pl.BlockSpec((None, 1, bs, g * hd), page_map),
     ]
-    args = [q4, k_flat, v_flat]
+    args = [q4, k_pages, v_pages]
     if fp8:
-        in_specs += [pl.BlockSpec((1, bs, hkv), scale_map)] * 2
+        in_specs += [pl.BlockSpec((None, 1, bs, hkv), scale_map)] * 2
         args += [k_scale, v_scale]
     else:
         # dummy scalars (kernel ignores them when fp8=False)
         in_specs += [pl.BlockSpec((1, 1),
-                                  lambda bi, hi, ji, bt, pos: (0, 0))] * 2
+                                  lambda bi, hi, ji, bt, pos, lyr: (0, 0))] * 2
         args += [jnp.zeros((1, 1), jnp.float32)] * 2
 
     kern = functools.partial(_attend_kernel, mb=mb, bs=bs, s_q=s_q, g=g,
@@ -200,17 +214,16 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b, hkv // g, mb),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, g, r, hd),
-                                   lambda bi, hi, ji, bt, pos: (bi, hi, 0, 0)),
+            out_specs=pl.BlockSpec((1, g, r, hd), q_map),
             scratch_shapes=[pltpu.VMEM((mb * bs, g * hd), q.dtype),
                             pltpu.VMEM((mb * bs, g * hd), q.dtype)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, r, hd), q.dtype),
         interpret=interpret,
-    )(bt, pos2, *args)
+    )(bt, pos2, lyr, *args)
 
     out = out.reshape(b, hkv, n_rep, s_q, hd).transpose(0, 3, 1, 2, 4)
     return out.reshape(b, s_q, h, hd)

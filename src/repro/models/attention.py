@@ -253,31 +253,40 @@ def cache_update_slots(layer_cache, k_new, v_new, positions, active):
 # ---------------------------------------------------------------------------
 # Paged KV pool (continuous-batching engine)
 #
-# The pool stores one layer's cache as [n_blocks, block_size, Hkv, hd]
-# (+ per-(slot-in-block, head) fp32 scales when FP8).  Requests own disjoint
-# block sets; a per-request block table maps logical position p to pool
-# location (table[p // block_size], p % block_size).  Unlike the dense
-# ring-buffer cache above there is no wraparound: the slot index inside the
-# gathered view IS the absolute position, so per-request masking is plain
-# position arithmetic.
+# The pool stores every layer's cache in one buffer per K/V,
+# [L, n_blocks, block_size, Hkv*hd] (+ per-(slot-in-block, head) fp32
+# scales [L, n_blocks, block_size, Hkv] when FP8) — the layout the fused
+# attention kernel reads, a row-major flat head axis.  Requests own
+# disjoint block sets; a per-request block table maps logical position p
+# to pool location (table[p // block_size], p % block_size).  Unlike the
+# dense ring-buffer cache above there is no wraparound: the slot index
+# inside the gathered view IS the absolute position, so per-request
+# masking is plain position arithmetic.  The paged forwards carry the
+# whole pool through their layer scan and address a layer by index, so
+# the pool is updated in place and no per-layer slice is ever copied out.
 # ---------------------------------------------------------------------------
 
+POOL_AXES = ("layers", "blocks", "blockslot", "kv")
 
-def paged_update_layer(pool_sl, k_new, v_new, block_tables, positions, active):
-    """Scatter new KV for S >= 1 positions per batch row into a pool layer.
 
-    pool_sl: {"k","v": [n_blocks, bs, Hkv, hd], optional "k_scale"/"v_scale"
-    [n_blocks, bs, Hkv]}.  k_new/v_new: [B, S, Hkv, hd] — S == 1 is the
-    one-token decode step; S == k+1 is the speculative verify step writing a
-    whole draft chunk at per-slot position offsets.  positions: [B] (S == 1)
-    or [B, S] absolute write positions; active: [B] or [B, S] bool —
-    inactive entries scatter out of bounds and are dropped (never corrupting
-    live blocks), which is also how verify masks a slot's unused draft tail.
-    FP8 pools quantize through the same ``_quant_kv`` as the dense cache
-    path, so a paged request's stored values match the static-batch cache
-    bit for bit.
+def paged_update_layer(pool, layer, k_new, v_new, block_tables, positions,
+                       active):
+    """Scatter new KV for S >= 1 positions per batch row into layer
+    ``layer`` of the whole pool, in place.
+
+    pool: {"k","v": [L, n_blocks, bs, Hkv*hd], optional "k_scale"/"v_scale"
+    [L, n_blocks, bs, Hkv]}; layer: the (traced) layer index.
+    k_new/v_new: [B, S, Hkv, hd] — S == 1 is the one-token decode step;
+    S == k+1 is the speculative verify step writing a whole draft chunk at
+    per-slot position offsets.  positions: [B] (S == 1) or [B, S] absolute
+    write positions; active: [B] or [B, S] bool — inactive entries scatter
+    out of bounds and are dropped (never corrupting live blocks), which is
+    also how verify masks a slot's unused draft tail.  Only the new rows
+    are reshaped to the flat head axis, never the pool.  FP8 pools quantize
+    through the same ``_quant_kv`` as the dense cache path, so a paged
+    request's stored values match the static-batch cache bit for bit.
     """
-    n_blocks, bs = pool_sl["k"].shape[:2]
+    n_blocks, bs = pool["k"].shape[1:3]
     if positions.ndim == 1:
         positions = positions[:, None]                # [B] -> [B, 1]
     active = jnp.broadcast_to(active[:, None] if active.ndim == 1 else active,
@@ -285,45 +294,49 @@ def paged_update_layer(pool_sl, k_new, v_new, block_tables, positions, active):
     blk = jnp.take_along_axis(block_tables, positions // bs, axis=1)  # [B, S]
     blk = jnp.where(active, blk, n_blocks)            # OOB -> dropped
     off = positions % bs
-    out = dict(pool_sl)
-    if pool_sl.get("k_scale") is not None:
+    b, s, hkv, hd = k_new.shape
+    out = dict(pool)
+    if pool.get("k_scale") is not None:
         kq, ks = _quant_kv(k_new)
         vq, vs = _quant_kv(v_new)
-        out["k"] = pool_sl["k"].at[blk, off].set(kq, mode="drop")
-        out["v"] = pool_sl["v"].at[blk, off].set(vq, mode="drop")
-        out["k_scale"] = pool_sl["k_scale"].at[blk, off].set(ks, mode="drop")
-        out["v_scale"] = pool_sl["v_scale"].at[blk, off].set(vs, mode="drop")
+        new = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
     else:
-        dt = pool_sl["k"].dtype
-        out["k"] = pool_sl["k"].at[blk, off].set(k_new.astype(dt),
-                                                 mode="drop")
-        out["v"] = pool_sl["v"].at[blk, off].set(v_new.astype(dt),
-                                                 mode="drop")
-    # TP: pages stay KV-head-sharded through the scatter (the block and
-    # slot dims are never sharded, so each shard writes its own heads)
-    pool_axes = ("blocks", "blockslot", "kv", "headdim")
-    return {name: cst(a, pool_axes if a.ndim == 4 else pool_axes[:-1])
-            for name, a in out.items()}
+        new = {"k": k_new, "v": v_new}
+    for name, x in new.items():
+        if name in ("k", "v"):
+            x = x.reshape(b, s, hkv * hd)
+        out[name] = pool[name].at[layer, blk, off].set(
+            x.astype(pool[name].dtype), mode="drop")
+    # TP: pages stay KV-head-sharded through the scatter (the layer, block
+    # and slot dims are never sharded, so each shard writes its own heads)
+    return {name: cst(a, POOL_AXES) for name, a in out.items()}
 
 
-def paged_gather_layer(pool_sl, block_tables, dtype=jnp.bfloat16):
-    """Gather per-request dense KV views [B, MB*bs, Hkv, hd] from the pool.
+def paged_gather_layer(pool, layer, block_tables, head_dim,
+                       dtype=jnp.bfloat16):
+    """Gather layer ``layer``'s per-request dense KV views
+    [B, MB*bs, Hkv, hd] straight from the whole pool.
 
     block_tables: [B, MB] pool block ids (entries for unallocated logical
     blocks may be arbitrary in-range ids — callers mask by position).
     """
     b, mb = block_tables.shape
     def dense(name):
-        g = pool_sl[name][block_tables]               # [B, MB, bs, ...]
+        g = pool[name][layer, block_tables]           # [B, MB, bs, F]
         return g.reshape(b, mb * g.shape[2], *g.shape[3:])
-    if pool_sl.get("k_scale") is not None:
-        return (_dequant_kv(dense("k"), dense("k_scale"), dtype),
-                _dequant_kv(dense("v"), dense("v_scale"), dtype))
-    return dense("k").astype(dtype), dense("v").astype(dtype)
+    k, v = dense("k"), dense("v")
+    hkv = k.shape[-1] // head_dim
+    k = k.reshape(b, -1, hkv, head_dim)
+    v = v.reshape(b, -1, hkv, head_dim)
+    if pool.get("k_scale") is not None:
+        return (_dequant_kv(k, dense("k_scale"), dtype),
+                _dequant_kv(v, dense("v_scale"), dtype))
+    return k.astype(dtype), v.astype(dtype)
 
 
-def paged_attend(q, pool_sl, block_tables, pos, *, window: int = 0):
-    """Decode/verify attention against the paged pool: q [B, S, H, hd].
+def paged_attend(q, pool, layer, block_tables, pos, *, window: int = 0):
+    """Decode/verify attention against layer ``layer`` of the paged pool:
+    q [B, S, H, hd].
 
     ``pos``: per-query valid-key counts (every attended position's KV must
     already be written) — [B] applies one count to every query (the S == 1
@@ -339,7 +352,8 @@ def paged_attend(q, pool_sl, block_tables, pos, *, window: int = 0):
     ``window`` masks by absolute position (the pool keeps every block live
     for simplicity — no ring buffer).
     """
-    k, v = paged_gather_layer(pool_sl, block_tables, q.dtype)
+    k, v = paged_gather_layer(pool, layer, block_tables, q.shape[-1],
+                              q.dtype)
     b, s_alloc, hkv, hd = k.shape
     h = q.shape[2]
     # TP: the gathered per-slot views keep the pool's KV-head sharding, and
@@ -364,10 +378,12 @@ def paged_attend(q, pool_sl, block_tables, pos, *, window: int = 0):
     return out.astype(q.dtype)
 
 
-def paged_attend_fused(q, pool_sl, block_tables, pos, *, window: int = 0):
+def paged_attend_fused(q, pool, layer, block_tables, pos, *,
+                       window: int = 0):
     """``paged_attend`` through the fused Pallas kernel — page-table gather,
-    FP8 dequant, and attend in ONE pass over the block table, no dense
-    [B, MB*bs, Hkv, hd] intermediate in HBM.
+    FP8 dequant, and attend in ONE pass over the block table, reading the
+    layer's pages straight from the whole pool, no dense
+    [B, MB*bs, Hkv, hd] intermediate and no layer slice in HBM.
 
     Same contract as ``paged_attend`` (its parity oracle: bitwise for BF16
     pools — the kernel defers softmax until the fully-masked score strip is
@@ -378,4 +394,5 @@ def paged_attend_fused(q, pool_sl, block_tables, pos, *, window: int = 0):
     (``serve.engine`` resolves ``fused_kernels="auto"`` accordingly).
     """
     from repro.kernels import ops
-    return ops.paged_attention(q, pool_sl, block_tables, pos, window=window)
+    return ops.paged_attention(q, pool, layer, block_tables, pos,
+                               window=window)

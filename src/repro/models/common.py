@@ -160,6 +160,17 @@ def scan_layers(body_fn, carry, stacked_params, stacked_xs, qcfg,
     stacked [n, ...] leading dim (PTQ gives packed leaves per-layer tensor
     scales shaped [n, 1, ...] for exactly this reason), so the body receives
     per-layer ``PackedNVFP4`` slices with their static metadata intact.
+
+    Carried-state form: state that a step updates a few rows of per layer
+    (the paged KV pool) rides in ``carry`` beside the activations, and
+    ``stacked_xs=jnp.arange(n)`` hands the body its absolute layer index
+    (the segments slice it like the params, so the count runs across the
+    skip segments); the body writes at ``[layer, ...]`` of the whole
+    buffer and returns ``ys=None``.  Scanning such state as ``xs``/``ys``
+    instead makes XLA slice every layer out, scatter into the slice, write
+    it to a separate ``ys`` buffer and copy that into the output: a second
+    copy of the state and a whole-layer copy per layer per step.  In the
+    carry, the buffer is updated in place.
     """
     from repro.core.qconfig import BF16
 

@@ -338,22 +338,30 @@ def _block_slots(qcfg, cfg, p, x, lens, active, cache_sl):
 def paged_pool_specs(cfg, n_blocks: int, block_size: int):
     """ParamSpecs for the block-granular KV pool shared by all requests.
 
-    Layout [L, n_blocks, block_size, Hkv, hd]; FP8 pools (moe_hybrid recipe)
-    carry per-(slot, head) fp32 scales next to the E4M3 pages, exactly like
-    the dense cache.  Also used abstractly by the dry-run to price the pool.
+    K and V pages are [L, n_blocks, block_size, Hkv*hd]: the layout the
+    fused attention kernel reads, so a page's heads lie side by side on the
+    lanes and no step ever relayouts the pool (a separate [.., Hkv, hd]
+    minor pair is tiled differently on the TPU at hd 64).  The flat head
+    axis is row-major, so a head's hd lanes stay together and TP shards on
+    "kv" stay head-local wherever the TP degree divides Hkv.  FP8 pools
+    (moe_hybrid recipe) carry per-(slot, head) fp32 scales
+    [L, n_blocks, block_size, Hkv] next to the E4M3 pages, exactly like
+    the dense cache.  Also used abstractly by the dry-run to price the
+    pool.
     """
     P = common.ParamSpec
     fp8 = _kv_fp8(cfg)
     kdt = jnp.float8_e4m3fn if fp8 else jnp.bfloat16
-    shape = (cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
-    axes = ("layers", "blocks", "blockslot", "kv", "headdim")
-    c = {"k": P(shape, axes, dtype=kdt, init="zeros"),
-         "v": P(shape, axes, dtype=kdt, init="zeros")}
+    lead = (cfg.n_layers, n_blocks, block_size)
+    c = {"k": P((*lead, cfg.n_kv_heads * cfg.head_dim), attn.POOL_AXES,
+                dtype=kdt, init="zeros"),
+         "v": P((*lead, cfg.n_kv_heads * cfg.head_dim), attn.POOL_AXES,
+                dtype=kdt, init="zeros")}
     if fp8:
-        c["k_scale"] = P(shape[:-1], axes[:-1], dtype=jnp.float32,
-                         init="zeros")
-        c["v_scale"] = P(shape[:-1], axes[:-1], dtype=jnp.float32,
-                         init="zeros")
+        c["k_scale"] = P((*lead, cfg.n_kv_heads), attn.POOL_AXES,
+                         dtype=jnp.float32, init="zeros")
+        c["v_scale"] = P((*lead, cfg.n_kv_heads), attn.POOL_AXES,
+                         dtype=jnp.float32, init="zeros")
     return c
 
 
@@ -382,6 +390,8 @@ def write_prompt_to_pool(pool, cache, block_ids):
     ``cache``: the dict ``prefill(..., s_max=None)`` returns, minus "pos";
     ``block_ids``: [ceil(P / block_size)] pool block ids.  Tail positions of
     the last block are zero-filled (masked by the request length at read).
+    The cache's [.., Hkv, hd] rows are reshaped to the pool's flat head
+    axis on the way (the prompt's rows, never the pool).
     """
     bs = pool["k"].shape[2]
     out = dict(pool)
@@ -393,14 +403,16 @@ def write_prompt_to_pool(pool, cache, block_ids):
         if pad:
             c = jnp.pad(c, [(0, 0), (0, 0), (0, pad)]
                         + [(0, 0)] * (c.ndim - 3))
-        blocks = c[:, 0].reshape(l, (p_len + pad) // bs, bs, *c.shape[3:])
+        blocks = c[:, 0].reshape(l, (p_len + pad) // bs, bs,
+                                 pool[name].shape[3])
         out[name] = pool[name].at[:, ids].set(blocks.astype(pool[name].dtype))
     return out
 
 
-def _attention_paged(qcfg, cfg, p, h, pos, psl, block_tables, positions,
-                     active, fused: bool = False):
-    """Paged attention for S >= 1 new positions per slot.
+def _attention_paged(qcfg, cfg, p, h, pos, pool, layer, block_tables,
+                     positions, active, fused: bool = False):
+    """Paged attention for S >= 1 new positions per slot, against layer
+    ``layer`` of the whole (carried) pool; returns (out, new_pool).
 
     ``positions``: [B] (one-token decode) or [B, S] (multi-token verify)
     absolute write positions — RoPE ``pos`` must address the same positions;
@@ -426,15 +438,15 @@ def _attention_paged(qcfg, cfg, p, h, pos, psl, block_tables, positions,
     q = cst(_rope(cfg, attn.split_heads(q, nh, hd), pos), hax)
     k = cst(_rope(cfg, attn.split_heads(k, nkv, hd), pos), kax)
     v = cst(attn.split_heads(v, nkv, hd), kax)
-    new_psl = attn.paged_update_layer(psl, k, v, block_tables, positions,
-                                      active)
+    pool = attn.paged_update_layer(pool, layer, k, v, block_tables,
+                                   positions, active)
     attend = attn.paged_attend_fused if fused else attn.paged_attend
-    out = cst(attend(q, new_psl, block_tables, positions + 1,
+    out = cst(attend(q, pool, layer, block_tables, positions + 1,
                      window=cfg.window), hax)
     out = cst(layers.qdense(qcfg, "attn", out.reshape(b, s, nh * hd), p["wo"],
                             parallelism="row"),
               ("batch", "seq", "none"))
-    return out, new_psl
+    return out, pool
 
 
 def decode_step_paged(cfg, params, pool, block_tables, lens, active, batch,
@@ -446,33 +458,16 @@ def decode_step_paged(cfg, params, pool, block_tables, lens, active, batch,
     Inactive slots compute garbage logits (the engine ignores them) but
     their pool writes are dropped, so live blocks are never corrupted.
     ``fused`` (static) selects the one-pass fused paged-attention kernel.
+    The whole pool rides in the layer scan's carry and each layer writes
+    and reads it at its own index (``scan_layers``' carried-state form), so
+    a donated pool is updated in place: no layer slice, no second pool.
     Returns (logits [n_slots, 1, V], new_pool).
     """
     if cfg.mrope_sections:
         raise NotImplementedError("paged decode does not support M-RoPE")
     x = _embed_inputs(cfg, params, batch)
-    pos = lens[:, None]                               # per-slot RoPE positions
-
-    def body(qc):
-        def fn(carry, inp):
-            p, psl = inp
-            h = run_norm(cfg, p["ln1"], carry)
-            a, new_psl = _attention_paged(qc, cfg, p, h, pos, psl,
-                                          block_tables, lens, active,
-                                          fused=fused)
-            y = carry + a
-            h = run_norm(cfg, p["ln2"], y)
-            f, _ = _ffn(qc, cfg, p, h)
-            return y + f, new_psl
-        return fn
-
-    x, new_pool = common.scan_layers(
-        body, x, params["layers"], pool, qcfg,
-        qcfg.skip_first_layers, qcfg.skip_last_layers, "none")
-    x = run_norm(cfg, params["final_norm"], x)
-    logits = layers.qdense(qcfg, "lm_head", x, unembed(cfg, params),
-                          parallelism="column")
-    return logits, new_pool
+    return _paged_layers(cfg, params, pool, x, lens[:, None], block_tables,
+                         lens, active, qcfg, fused)
 
 
 def verify_step_paged(cfg, params, pool, block_tables, lens, active, n_prop,
@@ -510,31 +505,38 @@ def verify_step_paged(cfg, params, pool, block_tables, lens, active, n_prop,
     offs = jnp.arange(k1)
     positions = lens[:, None] + offs[None, :]          # [n_slots, K1]
     tok_active = active[:, None] & (offs[None, :] <= n_prop[:, None])
+    return _paged_layers(cfg, params, pool, x, positions, block_tables,
+                         positions, tok_active, qcfg, fused)
 
+
+def _paged_layers(cfg, params, pool, x, pos, block_tables, positions, active,
+                  qcfg, fused):
+    """The layer scan of decode and verify, then the head: the pool rides
+    in the carry and each layer writes and reads it at its own index."""
     def body(qc):
         def fn(carry, inp):
-            p, psl = inp
-            h = run_norm(cfg, p["ln1"], carry)
-            a, new_psl = _attention_paged(qc, cfg, p, h, positions, psl,
-                                          block_tables, positions, tok_active,
-                                          fused=fused)
-            y = carry + a
+            (x, pool), (p, layer) = carry, inp
+            h = run_norm(cfg, p["ln1"], x)
+            a, pool = _attention_paged(qc, cfg, p, h, pos, pool, layer,
+                                       block_tables, positions, active,
+                                       fused=fused)
+            y = x + a
             h = run_norm(cfg, p["ln2"], y)
             f, _ = _ffn(qc, cfg, p, h)
-            return y + f, new_psl
+            return (y + f, pool), None
         return fn
 
-    x, new_pool = common.scan_layers(
-        body, x, params["layers"], pool, qcfg,
+    (x, new_pool), _ = common.scan_layers(
+        body, (x, pool), params["layers"], jnp.arange(cfg.n_layers), qcfg,
         qcfg.skip_first_layers, qcfg.skip_last_layers, "none")
     x = run_norm(cfg, params["final_norm"], x)
     logits = layers.qdense(qcfg, "lm_head", x, unembed(cfg, params),
-                          parallelism="column")
+                           parallelism="column")
     return logits, new_pool
 
 
-def _attention_prefill_chunk(qcfg, cfg, p, h, pos, ssl, psl, bt, positions,
-                             tok_active, start, n_valid):
+def _attention_prefill_chunk(qcfg, cfg, p, h, pos, scratch, pool, layer, bt,
+                             positions, tok_active, start, n_valid):
     b, c, _ = h.shape                                 # b == 1
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     qkv = layers.qdense(qcfg, "attn", h, p["wqkv"], p.get("bqkv"),
@@ -545,23 +547,22 @@ def _attention_prefill_chunk(qcfg, cfg, p, h, pos, ssl, psl, bt, positions,
     k = cst(_rope(cfg, attn.split_heads(k, nkv, hd), pos),
             ("batch", "seq", "kv", "none"))
     v = cst(attn.split_heads(v, nkv, hd), ("batch", "seq", "kv", "none"))
-    new_ssl = {
-        "k": jax.lax.dynamic_update_slice_in_dim(
-            ssl["k"], k.astype(ssl["k"].dtype), start, axis=1),
-        "v": jax.lax.dynamic_update_slice_in_dim(
-            ssl["v"], v.astype(ssl["v"].dtype), start, axis=1),
-    }
-    out = attn.blockwise_attention(q, new_ssl["k"], new_ssl["v"], causal=True,
-                                   window=cfg.window, q_offset=start,
-                                   kv_valid=start + n_valid)
+    # the scratch is carried whole too: written in place at [layer, 0, start]
+    scratch = {name: jax.lax.dynamic_update_slice(
+        scratch[name], x[None].astype(scratch[name].dtype),
+        (layer, 0, start, 0, 0)) for name, x in (("k", k), ("v", v))}
+    out = attn.blockwise_attention(q, scratch["k"][layer], scratch["v"][layer],
+                                   causal=True, window=cfg.window,
+                                   q_offset=start, kv_valid=start + n_valid)
     # pool copy (FP8 pools quantize here) for later decode reads; one write
     # per chunk token, pad tokens dropped
-    new_psl = attn.paged_update_layer(psl, k.swapaxes(0, 1), v.swapaxes(0, 1),
-                                      bt, positions, tok_active)
+    pool = attn.paged_update_layer(pool, layer, k.swapaxes(0, 1),
+                                   v.swapaxes(0, 1), bt, positions,
+                                   tok_active)
     out = cst(layers.qdense(qcfg, "attn", out.reshape(b, c, nh * hd), p["wo"],
                             parallelism="row"),
               ("batch", "seq", "none"))
-    return out, new_ssl, new_psl
+    return out, scratch, pool
 
 
 def prefill_chunk_paged(cfg, params, scratch, pool, block_table, start,
@@ -573,8 +574,10 @@ def prefill_chunk_paged(cfg, params, scratch, pool, block_table, start,
     ``block_table``: [MB] this request's pool blocks; ``start``: tokens
     already prefilled (traced); ``n_valid``: valid tokens in this chunk
     (traced, 1..C).  Returns (logits at the last valid position [1, 1, V],
-    new_scratch, new_pool).  Shapes are static across chunks and requests,
-    so the engine compiles this once per chunk size.
+    new_scratch, new_pool).  Scratch and pool both ride in the layer scan's
+    carry and are written in place at each layer's index.  Shapes are
+    static across chunks and requests, so the engine compiles this once per
+    chunk size.
     """
     if cfg.mrope_sections:
         raise NotImplementedError("paged prefill does not support M-RoPE")
@@ -587,20 +590,21 @@ def prefill_chunk_paged(cfg, params, scratch, pool, block_table, start,
 
     def body(qc):
         def fn(carry, inp):
-            p, (ssl, psl) = inp
-            h = run_norm(cfg, p["ln1"], carry)
-            a, new_ssl, new_psl = _attention_prefill_chunk(
-                qc, cfg, p, h, pos, ssl, psl, bt, positions, tok_active,
-                start, n_valid)
-            y = carry + a
+            (x, scratch, pool), (p, layer) = carry, inp
+            h = run_norm(cfg, p["ln1"], x)
+            a, scratch, pool = _attention_prefill_chunk(
+                qc, cfg, p, h, pos, scratch, pool, layer, bt, positions,
+                tok_active, start, n_valid)
+            y = x + a
             h = run_norm(cfg, p["ln2"], y)
             f, _ = _ffn(qc, cfg, p, h)
-            return y + f, (new_ssl, new_psl)
+            return (y + f, scratch, pool), None
         return fn
 
-    x, (new_scratch, new_pool) = common.scan_layers(
-        body, x, params["layers"], (scratch, pool), qcfg,
-        qcfg.skip_first_layers, qcfg.skip_last_layers, "none")
+    (x, new_scratch, new_pool), _ = common.scan_layers(
+        body, (x, scratch, pool), params["layers"],
+        jnp.arange(cfg.n_layers), qcfg, qcfg.skip_first_layers,
+        qcfg.skip_last_layers, "none")
     x = run_norm(cfg, params["final_norm"], x)
     x_last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
     logits = layers.qdense(qcfg, "lm_head", x_last, unembed(cfg, params),

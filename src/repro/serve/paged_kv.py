@@ -1,12 +1,14 @@
 """Paged KV cache pool: fixed block inventory shared by all requests.
 
-The pool owns the device tensors ([L, n_blocks, block_size, Hkv, hd] per
-K/V, plus fp32 scale planes for FP8 layouts) and a host-side allocator.
-Requests hold block sets; the engine passes per-slot block tables into the
-jitted paged forwards (``repro.models.decoder``), which gather/scatter
-through them.  Allocation and free are host-side and O(blocks); the device
-tensors never reallocate, so jitted step shapes stay static for the life
-of the engine.
+The pool owns the device tensors ([L, n_blocks, block_size, Hkv*hd] per
+K/V, the layout the fused attention kernel reads, plus fp32
+[L, n_blocks, block_size, Hkv] scale planes for FP8 layouts) and a
+host-side allocator.  Requests hold block sets; the engine passes per-slot
+block tables into the jitted paged forwards (``repro.models.decoder``),
+which carry the whole pool through their layer scan and gather/scatter
+through the tables in place.  Allocation and free are host-side and
+O(blocks); the device tensors never reallocate, so jitted step shapes stay
+static for the life of the engine.
 
 Blocks are refcounted so a prefix cache can share one physical block
 across many requests.  A block is in exactly one of three states:

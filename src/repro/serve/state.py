@@ -289,6 +289,8 @@ class PagedKVState:
                 raise PoolExhausted("no free block for copy-on-write split")
             [nb] = self.pool.alloc(1)
             if self._copy_fn is None:
+                # every layer's page of block src -> dst, whatever the
+                # planes' minor dims ([.., Hkv*hd] pages, [.., Hkv] scales)
                 self._copy_fn = jax.jit(
                     lambda data, src, dst: {
                         k: v.at[:, dst].set(v[:, src])

@@ -11,6 +11,7 @@ tests loads the library, and it skips them where it cannot be loaded.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
@@ -111,11 +112,53 @@ def test_paged_attention_compiles(one_chip, q_len):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     q = s((SLOTS, q_len, CFG.n_heads, HD), jnp.bfloat16)
-    pages = s((n_blocks, BLOCK_SIZE, nkv, HD), jnp.bfloat16)
+    # the whole pool in its stored layout; the kernel reads one layer
+    pages = s((CFG.n_layers, n_blocks, BLOCK_SIZE, nkv * HD), jnp.bfloat16)
     bt = s((SLOTS, PAGES), jnp.int32)
     pos = s((SLOTS,) if q_len == 1 else (SLOTS, q_len), jnp.int32)
-    _mosaic(lambda q, k, v, bt, pos: pa.paged_attention(
-        q, k, v, bt, pos, interpret=False), q, pages, pages, bt, pos)
+    layer = s((), jnp.int32)
+    _mosaic(lambda q, k, v, bt, pos, layer: pa.paged_attention(
+        q, k, v, bt, pos, layer, interpret=False), q, pages, pages, bt, pos,
+        layer)
+
+
+def test_decode_step_updates_pool_in_place(one_chip, monkeypatch):
+    """The donated decode step with the fused kernel at qwen1.5-0.5b's
+    attention widths (two layers, BF16 weights): the pool rides in the
+    layer scan's carry and the kernel reads its layer from the whole pool,
+    so the step holds no copy of the pool or of one layer, and temporaries
+    under 5% of the pool's bytes."""
+    from test_paged_carry import assert_pool_updated_in_place
+
+    from repro.core import qconfig
+    from repro.kernels import ops
+    from repro.models import decoder
+
+    cfg = dataclasses.replace(CFG, n_layers=2)
+    params = _on(jax.eval_shape(
+        lambda: decoder.init_params(cfg, jax.random.PRNGKey(0))), one_chip)
+    pool = _on(jax.eval_shape(
+        lambda: decoder.init_paged_pool(cfg, 512, BLOCK_SIZE)), one_chip)
+
+    def s(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    step = jax.jit(
+        lambda params, pool, bt, lens, active, toks:
+        decoder.decode_step_paged(cfg, params, pool, bt, lens, active,
+                                  {"tokens": toks}, qconfig.BF16,
+                                  fused=True),
+        donate_argnums=(1,))
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    ops.interpret_default.cache_clear()
+    try:
+        compiled = step.lower(params, pool, s((SLOTS, PAGES)), s((SLOTS,)),
+                              s((SLOTS,), jnp.bool_),
+                              s((SLOTS, 1))).compile()
+    finally:
+        ops.interpret_default.cache_clear()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert_pool_updated_in_place(compiled, pool)
 
 
 @pytest.mark.parametrize("parallelism,k,n",

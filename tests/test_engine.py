@@ -81,7 +81,10 @@ def test_pool_fp8_pages_carry_scales():
     pool = PagedKVPool(data, 8)
     assert pool.fp8
     assert data["k"].dtype == jnp.float8_e4m3fn
-    assert data["k_scale"].shape == data["k"].shape[:-1]
+    # pages [L, n_blocks, bs, Hkv*hd], one scale per (slot, head)
+    assert data["k"].shape == (cfg.n_layers, 4, 8,
+                               cfg.n_kv_heads * cfg.head_dim)
+    assert data["k_scale"].shape == (*data["k"].shape[:-1], cfg.n_kv_heads)
     assert data["k_scale"].dtype == jnp.float32
     # pool bytes charge pages AND scales
     assert pool.nbytes() == sum(int(a.nbytes) for a in data.values())

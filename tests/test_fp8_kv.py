@@ -109,29 +109,37 @@ def test_paged_fp8_write_matches_dense_cache_write():
     pos = 5
     dense_out = attn.cache_update_layer(dense, k_new, v_new, pos)
 
-    n_blocks = 6
-    pool = {"k": jnp.zeros((n_blocks, bs, h, hd), jnp.float8_e4m3fn),
-            "v": jnp.zeros((n_blocks, bs, h, hd), jnp.float8_e4m3fn),
-            "k_scale": jnp.zeros((n_blocks, bs, h), jnp.float32),
-            "v_scale": jnp.zeros((n_blocks, bs, h), jnp.float32)}
+    n_blocks, n_layers, layer = 6, 3, 1
+    pool = {"k": jnp.zeros((n_layers, n_blocks, bs, h * hd),
+                           jnp.float8_e4m3fn),
+            "v": jnp.zeros((n_layers, n_blocks, bs, h * hd),
+                           jnp.float8_e4m3fn),
+            "k_scale": jnp.zeros((n_layers, n_blocks, bs, h), jnp.float32),
+            "v_scale": jnp.zeros((n_layers, n_blocks, bs, h), jnp.float32)}
     # rows 0/2 active with distinct block tables; row 1 inactive
     tables = jnp.asarray([[0, 1], [2, 3], [4, 5]], jnp.int32)
     lens = jnp.full((b,), pos, jnp.int32)
     active = jnp.asarray([True, False, True])
-    pool_out = attn.paged_update_layer(pool, k_new, v_new, tables, lens,
-                                       active)
+    pool_out = attn.paged_update_layer(pool, layer, k_new, v_new, tables,
+                                       lens, active)
 
     blk, off = pos // bs, pos % bs
     for row in (0, 2):
         pb = int(tables[row, blk])
         np.testing.assert_array_equal(
-            np.asarray(pool_out["k"][pb, off], np.float32),
-            np.asarray(dense_out["k"][row, pos], np.float32))
+            np.asarray(pool_out["k"][layer, pb, off], np.float32),
+            np.asarray(dense_out["k"][row, pos], np.float32).reshape(-1))
         np.testing.assert_array_equal(
-            np.asarray(pool_out["k_scale"][pb, off]),
+            np.asarray(pool_out["k_scale"][layer, pb, off]),
             np.asarray(dense_out["k_scale"][row, pos]))
-    # the inactive row (tables [2, 3]) wrote nothing anywhere
+    # the inactive row (tables [2, 3]) wrote nothing anywhere, and no other
+    # layer was touched
     np.testing.assert_array_equal(
-        np.asarray(pool_out["k"][2:4], np.float32), np.zeros((2, bs, h, hd)))
-    np.testing.assert_array_equal(np.asarray(pool_out["k_scale"][2:4]),
+        np.asarray(pool_out["k"][layer, 2:4], np.float32),
+        np.zeros((2, bs, h * hd)))
+    np.testing.assert_array_equal(np.asarray(pool_out["k_scale"][layer, 2:4]),
                                   np.zeros((2, bs, h)))
+    for other in (0, 2):
+        np.testing.assert_array_equal(
+            np.asarray(pool_out["k"][other], np.float32),
+            np.zeros((n_blocks, bs, h * hd)))

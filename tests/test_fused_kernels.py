@@ -26,16 +26,25 @@ def _bitwise(got, want):
                           np.asarray(want, np.float32))
 
 
+# layers in a test pool, and the one the attend cases read: every layer
+# holds different values, so reading the wrong one cannot pass
+N_LAYERS, LAYER = 3, 1
+
+
 def _pool(key, n_blocks, bs, hkv, hd, fp8=False):
-    k = jax.random.normal(key, (n_blocks, bs, hkv, hd), jnp.float32)
-    v = jax.random.normal(jax.random.fold_in(key, 1),
-                          (n_blocks, bs, hkv, hd), jnp.float32)
+    """A whole pool in the stored layout: pages [L, n_blocks, bs, Hkv*hd],
+    FP8 scales [L, n_blocks, bs, Hkv]."""
+    shape = (N_LAYERS, n_blocks, bs, hkv, hd)
+    k = jax.random.normal(key, shape, jnp.float32)
+    v = jax.random.normal(jax.random.fold_in(key, 1), shape, jnp.float32)
+    flat = lambda x: x.reshape(*shape[:3], hkv * hd)
     if fp8:
         kq = nvfp4.fp8_quantize(k, axis=-1)
         vq = nvfp4.fp8_quantize(v, axis=-1)
-        return {"k": kq.values, "v": vq.values,
+        return {"k": flat(kq.values), "v": flat(vq.values),
                 "k_scale": kq.scale[..., 0], "v_scale": vq.scale[..., 0]}
-    return {"k": k.astype(jnp.bfloat16), "v": v.astype(jnp.bfloat16)}
+    return {"k": flat(k).astype(jnp.bfloat16),
+            "v": flat(v).astype(jnp.bfloat16)}
 
 
 def _case(key, b, mb, bs, hkv, n_rep, hd, s_q=1, fp8=False):
@@ -68,8 +77,8 @@ def _case(key, b, mb, bs, hkv, n_rep, hd, s_q=1, fp8=False):
 def test_fused_attend_decode_bitwise_bf16(b, mb, bs, hkv, n_rep, hd):
     q, pool, bt, pos = _case(jax.random.PRNGKey(b + mb + hd), b, mb, bs,
                              hkv, n_rep, hd)
-    got = attn.paged_attend_fused(q, pool, bt, pos)
-    want = attn.paged_attend(q, pool, bt, pos)
+    got = attn.paged_attend_fused(q, pool, LAYER, bt, pos)
+    want = attn.paged_attend(q, pool, LAYER, bt, pos)
     assert got.dtype == want.dtype == jnp.bfloat16
     assert _bitwise(got, want)
 
@@ -80,8 +89,8 @@ def test_fused_attend_verify_multiquery_bitwise(s_q):
     reproduce sequential one-token decode bitwise."""
     q, pool, bt, pos = _case(jax.random.PRNGKey(40 + s_q), 3, 4, 16, 2, 2,
                              64, s_q=s_q)
-    got = attn.paged_attend_fused(q, pool, bt, pos)
-    want = attn.paged_attend(q, pool, bt, pos)
+    got = attn.paged_attend_fused(q, pool, LAYER, bt, pos)
+    want = attn.paged_attend(q, pool, LAYER, bt, pos)
     assert _bitwise(got, want)
 
 
@@ -92,12 +101,12 @@ def test_fused_attend_window_matches_oracle(window, s_q):
     agree with ``paged_attend(window=...)`` for decode AND verify shapes."""
     q, pool, bt, pos = _case(jax.random.PRNGKey(7 + window), 2, 4, 16, 2, 2,
                              64, s_q=s_q)
-    got = attn.paged_attend_fused(q, pool, bt, pos, window=window)
-    want = attn.paged_attend(q, pool, bt, pos, window=window)
+    got = attn.paged_attend_fused(q, pool, LAYER, bt, pos, window=window)
+    want = attn.paged_attend(q, pool, LAYER, bt, pos, window=window)
     assert _bitwise(got, want)
     if window < 40:
         # the window must actually bite: unwindowed output differs
-        assert not _bitwise(got, attn.paged_attend(q, pool, bt, pos))
+        assert not _bitwise(got, attn.paged_attend(q, pool, LAYER, bt, pos))
 
 
 @pytest.mark.parametrize("s_q", [1, 4])
@@ -107,8 +116,8 @@ def test_fused_attend_fp8_pool(s_q):
     fused output is per-element identical to the oracle."""
     q, pool, bt, pos = _case(jax.random.PRNGKey(60 + s_q), 3, 3, 16, 2, 3,
                              64, s_q=s_q, fp8=True)
-    got = attn.paged_attend_fused(q, pool, bt, pos)
-    want = attn.paged_attend(q, pool, bt, pos)
+    got = attn.paged_attend_fused(q, pool, LAYER, bt, pos)
+    want = attn.paged_attend(q, pool, LAYER, bt, pos)
     assert _bitwise(got, want)
 
 
@@ -117,16 +126,38 @@ def test_fused_attend_ignores_dead_table_tail():
     unwritten pages hold — poison the tail blocks and re-check."""
     q, pool, bt, pos = _case(jax.random.PRNGKey(5), 2, 4, 8, 2, 2, 32)
     pos = jnp.minimum(pos, 9)                      # keep >3 blocks dead
-    want = attn.paged_attend_fused(q, pool, bt, pos)
+    want = attn.paged_attend_fused(q, pool, LAYER, bt, pos)
     poisoned = dict(pool)
-    live = np.zeros(pool["k"].shape[0], bool)
+    live = np.zeros(pool["k"].shape[1], bool)
     live[np.asarray(bt[:, :2]).ravel()] = True     # blocks holding pos < 16
     noise = (1e3 * jax.random.normal(jax.random.PRNGKey(6), pool["k"].shape)
              ).astype(pool["k"].dtype)
-    dead = ~jnp.asarray(live)[:, None, None, None]
+    dead = ~jnp.asarray(live)[None, :, None, None]
     poisoned["k"] = jnp.where(dead, noise, pool["k"])
     poisoned["v"] = jnp.where(dead, noise, pool["v"])
-    assert _bitwise(attn.paged_attend_fused(q, poisoned, bt, pos), want)
+    assert _bitwise(attn.paged_attend_fused(q, poisoned, LAYER, bt, pos), want)
+
+
+@pytest.mark.parametrize("fp8", [False, True], ids=["bf16", "fp8"])
+@pytest.mark.parametrize("s_q", [1, 4], ids=["decode", "verify_k3"])
+def test_fused_attend_reads_the_layer_operand(s_q, fp8):
+    """The kernel's layer operand picks its layer out of the whole pool:
+    at every layer it matches the two-step oracle reading the same layer,
+    and that layer alone as a one-layer pool; layers hold different
+    values, so the outputs differ from layer to layer."""
+    q, pool, bt, pos = _case(jax.random.PRNGKey(80 + s_q), 3, 4, 16, 2, 2,
+                             64, s_q=s_q, fp8=fp8)
+    fused = jax.jit(attn.paged_attend_fused)
+    outs = []
+    for layer in range(N_LAYERS):
+        got = fused(q, pool, jnp.int32(layer), bt, pos)
+        assert _bitwise(got, attn.paged_attend(q, pool, layer, bt, pos))
+        alone = {k: v[layer:layer + 1] for k, v in pool.items()}
+        assert _bitwise(got, attn.paged_attend_fused(q, alone, 0, bt, pos))
+        outs.append(got)
+    for i in range(N_LAYERS):
+        for j in range(i):
+            assert not _bitwise(outs[i], outs[j])
 
 
 # ---------------------------------------------------------------------------
